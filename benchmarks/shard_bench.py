@@ -2,7 +2,9 @@
 
 Runs the four Pallas-kernel paths, the packed score cell and the shard_map
 train step on a 1x1 mesh and on real multi-device meshes (1x4, 2x2 by
-default — CPU devices are virtualized before jax initializes), records p50
+default — with ``--devices N`` the CPU backend is split into N virtual
+devices before jax initializes; without it the visible devices are used
+as they are), records p50
 step wall-clock per mesh, and parses the compiled post-SPMD HLO of the
 sharded lookup + train step with ``repro.launch.hlo_analysis`` to report the
 per-collective byte counts the roofline consumes
@@ -22,21 +24,21 @@ import os
 import sys
 
 
-def _early_devices() -> int:
+def _early_devices() -> int | None:
     """--devices must take effect before jax initializes its backend."""
     for i, a in enumerate(sys.argv):
         if a == "--devices" and i + 1 < len(sys.argv):
             return int(sys.argv[i + 1])
         if a.startswith("--devices="):
             return int(a.split("=", 1)[1])
-    return 4
+    return None
 
 
 _N_DEV = _early_devices()
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           f" --xla_force_host_platform_device_count={_N_DEV}"
-                           ).strip()
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if _N_DEV is not None:
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={_N_DEV}").strip()
 
 import json  # noqa: E402
 import platform  # noqa: E402
@@ -327,9 +329,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes (the CI data point)")
-    ap.add_argument("--devices", type=int, default=4,
-                    help="virtual CPU device count (consumed before jax "
-                         "initializes)")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="split the CPU backend into this many virtual "
+                         "devices (consumed before jax initializes; default: "
+                         "the visible devices)")
     ap.add_argument("--crossover-only", action="store_true",
                     help="run just the psum-vs-a2a crossover sweep (the "
                          "bench-gate data point; its counters are "

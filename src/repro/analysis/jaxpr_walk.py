@@ -40,10 +40,7 @@ class WalkItem(NamedTuple):
 
 
 def _user_frame(eqn):
-    try:
-        frame = source_info_util.user_frame(eqn.source_info)
-    except Exception:
-        return None, None
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     if frame is None:
         return None, None
     return frame.file_name, frame.start_line

@@ -20,7 +20,7 @@ One structural check runs on the traced jaxpr:
          train step's grads) must be followed by its ``psum``/``pmean``
          over exactly the row axes, or each device returns a partial
          result that the partitioner then treats as replicated (our
-         wrappers pass ``check_rep=False``, so jax itself won't catch it).
+         wrappers pass ``check_vma=False``, so jax itself won't catch it).
 """
 from __future__ import annotations
 
@@ -83,12 +83,12 @@ def check_celldef_specs(celldef) -> list[Finding]:
     return findings
 
 
-def _names_axes(names) -> set:
-    """Axes referenced by a shard_map in_names/out_names tuple-of-dicts."""
+def _specs_axes(specs) -> set:
+    """Mesh axes named by a shard_map equation's in_specs/out_specs."""
     axes = set()
-    for entry in names:
-        for axs in entry.values():
-            axes.update(axs)
+    for spec in _iter_specs(specs):
+        for entry in tuple(spec):
+            axes.update(normalize_entry(entry) or ())
     return axes
 
 
@@ -115,8 +115,8 @@ def check_shard_map_reductions(closed_jaxpr, where: str) -> list[Finding]:
         eqn = item.eqn
         if eqn.primitive.name != "shard_map":
             continue
-        in_axes = _names_axes(eqn.params.get("in_names", ()))
-        out_axes = _names_axes(eqn.params.get("out_names", ()))
+        in_axes = _specs_axes(eqn.params["in_specs"])
+        out_axes = _specs_axes(eqn.params["out_specs"])
         missing = in_axes - out_axes
         if not missing:
             continue

@@ -13,14 +13,15 @@ gather+unpack+dequant.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing
 from repro.core.mpe import MPEConfig
-from repro.core.quantizer import (dequantize_codes, int_bounds,
-                                  quantize_codes)
+from repro.core.quantizer import dequantize_codes, quantize_codes
 
 
 def _pad_rows(n: int, multiple: int) -> int:
@@ -39,6 +40,28 @@ def _auto_pad_multiple(n: int, n_widths: int, cap: int = 512) -> int:
     while m < cap and m * 2 * n_widths * 8 <= n:
         m *= 2
     return m
+
+
+#: rows quantized and packed per device program: the program's (rows, d)
+#: temporaries stay near 3 x 256 MB however large a width bucket is (one
+#: bucket of the Criteo-scale table is ~2 GB of float rows)
+_PACK_CHUNK_ROWS = 1 << 22
+
+
+@functools.partial(jax.jit, static_argnames=("b",))
+def _quantize_pack(rows, alpha, beta, *, b: int):
+    return packing.pack_codes(quantize_codes(rows, alpha, beta, b), b)
+
+
+def _pack_bucket(rows: np.ndarray, alpha, beta, b: int, padded: int):
+    """Packed words of ``rows`` at width ``b``, padded to ``padded`` rows.
+    Pad rows hold the most-negative code, whose packed words are all zero."""
+    parts = [_quantize_pack(jnp.asarray(rows[s:s + _PACK_CHUNK_ROWS]),
+                            alpha, beta, b=b)
+             for s in range(0, rows.shape[0], _PACK_CHUNK_ROWS)]
+    pad = jnp.zeros((padded - rows.shape[0],
+                     packing.words_per_row(rows.shape[1], b)), jnp.uint32)
+    return jnp.concatenate(parts + [pad], axis=0)
 
 
 def build_packed_table(emb, bits_idx_per_feature, alpha, beta, cfg: MPEConfig,
@@ -75,20 +98,17 @@ def build_packed_table(emb, bits_idx_per_feature, alpha, beta, cfg: MPEConfig,
         if b == 0:
             continue
         rows = emb[sel] if sel.size else np.zeros((0, d), emb.dtype)
-        codes = np.asarray(quantize_codes(jnp.asarray(rows), alpha_np[i], beta_np, int(b)))
         if row_capacities is not None:
             padded = int(row_capacities[f"b{b}"])
-            if codes.shape[0] > padded:
+            if rows.shape[0] > padded:
                 raise ValueError(
-                    f"width bucket b{b} holds {codes.shape[0]} rows, over its "
+                    f"width bucket b{b} holds {rows.shape[0]} rows, over its "
                     f"pinned capacity {padded} — a capacity-conforming repack "
                     f"must assign within the compiled subtable shapes")
         else:
-            padded = _pad_rows(codes.shape[0], row_pad_multiple)
-        n_b, _ = int_bounds(b)
-        codes_p = np.full((padded, d), n_b, np.int32)
-        codes_p[:codes.shape[0]] = codes
-        subtables[f"b{b}"] = jnp.asarray(np.asarray(packing.pack_codes(jnp.asarray(codes_p), b)))
+            padded = _pad_rows(rows.shape[0], row_pad_multiple)
+        subtables[f"b{b}"] = _pack_bucket(rows, alpha_np[i], beta_np,
+                                          int(b), padded)
 
     table = {
         "subtables": subtables,

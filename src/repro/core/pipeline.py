@@ -35,28 +35,44 @@ def jnp_array(x):
     return jnp.asarray(x)
 
 
+def _free_device_arrays(tree):
+    """Release the device buffers of every live ``jax.Array`` in ``tree``."""
+    for leaf in jax.tree.leaves(tree):
+        if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+            leaf.delete()
+
+
 def run_mpe_pipeline(build: Callable, data_fn: Callable, *, key,
                      mpe_cfg: MPEConfig, optimizer, search_steps: int,
                      retrain_steps: int, retrain_mode: str = "mpe",
                      eval_fn: Callable | None = None, log_fn=print,
                      ckpt_dir: str | None = None, prefetch: bool = False,
-                     mesh=None) -> dict:
+                     mesh=None, log_every: int = 100) -> dict:
+    """Search → sample → retrain → pack. ``log_every`` sets how often each
+    phase syncs to log its loss; the logged points come back per phase in
+    ``result["history"]`` (``Trainer.history``)."""
     comp_cfg = mpe_cfg._asdict()
 
     # ---------------- phase 1: precision search ----------------
     bundle = build(key, "mpe_search", comp_cfg)
-    params0 = jax.tree.map(lambda x: x, bundle["params"])  # shallow copy of refs
-    init_snapshot = jax.tree.map(np.asarray, params0)      # host copy of init
+    init_snapshot = jax.tree.map(np.asarray, bundle["params"])  # host copy
     trainer = Trainer(bundle["loss_fn"], bundle["params"], bundle["buffers"],
                       bundle["state"], optimizer, mesh=mesh,
                       ckpt_dir=None if ckpt_dir is None else f"{ckpt_dir}/search")
     trainer.restore()
     log_fn(f"[mpe] search phase: {search_steps} steps")
-    trainer.run(data_fn, search_steps, log_fn=log_fn, prefetch=prefetch)
+    trainer.run(data_fn, search_steps, log_fn=log_fn, prefetch=prefetch,
+                log_every=log_every)
     # host snapshots: the trainers donate their carries, so later phases must
     # not alias live device arrays from this one.
     search_params = jax.tree.map(np.asarray, trainer.params)
     search_state = jax.tree.map(np.asarray, trainer.state)
+    history = {"search": trainer.history}
+    # free the search phase's device state (table, optimizer moments) before
+    # the retrain phase allocates its own: at Criteo width each phase's step
+    # needs ~9 GB, two of them do not fit one 16 GB chip
+    _free_device_arrays(trainer.carry)
+    del trainer, bundle["params"]
 
     # ---------------- phase 2: precision sampling (Eq. 11) ----------------
     group_bits = sample_group_bits(search_params["embedding"], mpe_cfg)
@@ -105,8 +121,12 @@ def run_mpe_pipeline(build: Callable, data_fn: Callable, *, key,
     if steps:
         trainer2.restore()
         log_fn(f"[mpe] retrain phase ({retrain_mode}): {steps} steps")
-        trainer2.run(data_fn, steps, log_fn=log_fn, prefetch=prefetch)
+        trainer2.run(data_fn, steps, log_fn=log_fn, prefetch=prefetch,
+                     log_every=log_every)
+    history["retrain"] = trainer2.history
     final_params = trainer2.params
+    # the optimizer moments (2x the table) are done; packing needs the room
+    _free_device_arrays(trainer2.carry["opt"])
 
     # ---------------- phase 4: packed export ----------------
     table, meta = build_packed_table(final_params["embedding"]["emb"], fbits,
@@ -124,6 +144,7 @@ def run_mpe_pipeline(build: Callable, data_fn: Callable, *, key,
         "packed_table": table,
         "packed_meta": meta,
         "packed_bytes": packed_storage_bytes(table),
+        "history": history,
     }
     if eval_fn is not None:
         result["eval"] = eval_fn(final_params, retrain_buffers, trainer2.state)

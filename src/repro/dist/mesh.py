@@ -16,7 +16,7 @@ import contextlib
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 _MESH_STACK: list[Mesh] = []
 _DIST_INITIALIZED = False
@@ -104,12 +104,12 @@ def current_mesh() -> Mesh | None:
 
 
 def make_device_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...]) -> Mesh:
-    """Mesh over the available devices (prod 16×16 / 2×16×16, tests 1×N CPU)."""
-    try:
-        return jax.make_mesh(shape, axis_names)
-    except AttributeError:  # older jax: build the device grid by hand
-        from jax.experimental import mesh_utils
-        return Mesh(mesh_utils.create_device_mesh(shape), axis_names)
+    """Mesh over the available devices (prod 16×16 / 2×16×16, tests 1×N CPU).
+
+    Every axis is Auto, as in ``host_mesh``: the model code pins layouts
+    with ``with_sharding_constraint``, which only names Auto axes."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def parse_mesh_flag(flag: str | None) -> Mesh | None:

@@ -80,7 +80,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import packing
@@ -255,7 +255,7 @@ def _route_words(subs, widths, widx, lidx, shard, n_words, mask=None):
 
 
 def _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl, *, mesh, rows_ax,
-                bits, d, capacity, use_kernel, interpret, ok_vec=None):
+                bits, d, capacity, use_kernel, ok_vec=None):
     """Body of the capacity-bucketed all-to-all lookup (inside shard_map).
 
     The ids are replicated along ``rows_ax`` (they enter sharded over the
@@ -340,8 +340,7 @@ def _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl, *, mesh, rows_ax,
     for i, b in widths:
         wb = packing.words_per_row(d, b)
         deq = _bucket_dequant(full[:, :wb], jnp.arange(bp), alpha[i], beta,
-                              b=b, d=d, use_kernel=use_kernel,
-                              interpret=interpret)
+                              b=b, d=d, use_kernel=use_kernel)
         out = jnp.where((route & (widx == i))[:, None], deq, out)
     return out[:batch]
 
@@ -394,13 +393,12 @@ def lookup_route_stats(table, meta, ids, *, n_shards: int,
 # packed-table lookup (repro.kernels.mpe_lookup / core.inference)
 # ---------------------------------------------------------------------------
 
-def _bucket_dequant(sub, loc, alpha_i, beta, *, b, d, use_kernel, interpret):
+def _bucket_dequant(sub, loc, alpha_i, beta, *, b, d, use_kernel):
     """Device-local gather+unpack+dequant of one width bucket — the fused
     Pallas kernel or its jnp formulation, on local rows only."""
     if use_kernel:
         from repro.kernels.mpe_lookup.kernel import packed_lookup_pallas
-        return packed_lookup_pallas(loc, sub, alpha_i, beta, b=b, d=d,
-                                    interpret=interpret)
+        return packed_lookup_pallas(loc, sub, alpha_i, beta, b=b, d=d)
     words = jnp.take(sub, loc, axis=0)
     codes = packing.unpack_codes(words, b, d)
     return dequantize_codes(codes, alpha_i, beta)
@@ -408,7 +406,6 @@ def _bucket_dequant(sub, loc, alpha_i, beta, *, b, d, use_kernel, interpret):
 
 def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
                           mesh=None, use_kernel: bool = False,
-                          interpret: bool = True,
                           lookup_comms: str = "psum",
                           bucket_capacity: int | None = None):
     """``core.inference.packed_lookup`` under ``shard_map``: subtables
@@ -432,7 +429,7 @@ def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
     if mesh is None:
         if use_kernel:
             from repro.kernels.mpe_lookup.ops import packed_lookup_kernel
-            return packed_lookup_kernel(table, meta, ids, interpret=interpret)
+            return packed_lookup_kernel(table, meta, ids)
         return packed_lookup(table, meta, ids)
     rows_ax = _present_axes(mesh, rows_axes)
     mp = _axes_size(mesh, rows_ax)
@@ -451,7 +448,7 @@ def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
             return _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl,
                                mesh=mesh, rows_ax=rows_ax, bits=bits, d=d,
                                capacity=bucket_capacity,
-                               use_kernel=use_kernel, interpret=interpret)
+                               use_kernel=use_kernel)
         widx = jnp.take(width_idx, fl, axis=0)
         lidx = jnp.take(local_idx, fl, axis=0)
         base = rows_shard_index(mesh, rows_ax)
@@ -465,15 +462,15 @@ def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
             own = (loc >= 0) & (loc < rows_loc)
             deq = _bucket_dequant(sub, jnp.clip(loc, 0, rows_loc - 1),
                                   alpha[i], beta, b=b, d=d,
-                                  use_kernel=use_kernel, interpret=interpret)
+                                  use_kernel=use_kernel)
             out = jnp.where((own & (widx == i))[:, None], deq, out)
         # one non-zero owner per id: the psum adds zeros — exact
         return jax.lax.psum(out, rows_ax) if rows_ax else out
 
     in_specs = ({k: P(rows_ax or None, None) for k in tbl["subtables"]},
                 P(None), P(None), P(None), P(None), P(batch_ax))
-    out = shard_map(body, mesh, in_specs=in_specs,
-                    out_specs=P(batch_ax, None), check_rep=False)(
+    out = shard_map(body, mesh=mesh, in_specs=in_specs,
+                    out_specs=P(batch_ax, None), check_vma=False)(
         tbl["subtables"], tbl["local_idx"], tbl["width_idx"],
         tbl["alpha"], tbl["beta"], flat)
     return out.reshape(*ids.shape, d)
@@ -512,7 +509,7 @@ def sharded_tiered_hot_lookup(hot, bits, d: int, ids, *,
             return _a2a_lookup(subs, tier_local, width_idx, alpha, beta, fl,
                                mesh=mesh, rows_ax=rows_ax, bits=bits, d=d,
                                capacity=bucket_capacity, use_kernel=False,
-                               interpret=True, ok_vec=is_hot)
+                               ok_vec=is_hot)
         widx = jnp.take(width_idx, fl, axis=0)
         lidx = jnp.take(tier_local, fl, axis=0)
         hot_bit = jnp.take(is_hot, fl, axis=0)
@@ -533,8 +530,8 @@ def sharded_tiered_hot_lookup(hot, bits, d: int, ids, *,
 
     in_specs = ({k: P(rows_ax or None, None) for k in hot_p["subtables"]},
                 P(None), P(None), P(None), P(None), P(None), P(batch_ax))
-    out = shard_map(body, mesh, in_specs=in_specs,
-                    out_specs=P(batch_ax, None), check_rep=False)(
+    out = shard_map(body, mesh=mesh, in_specs=in_specs,
+                    out_specs=P(batch_ax, None), check_vma=False)(
         hot_p["subtables"], hot_p["tier_local"], hot_p["is_hot"],
         hot_p["width_idx"], hot_p["alpha"], hot_p["beta"], flat)
     return out.reshape(*ids.shape, d)
@@ -545,8 +542,7 @@ def sharded_tiered_hot_lookup(hot, bits, d: int, ids, *,
 # ---------------------------------------------------------------------------
 
 def sharded_embedding_bag(table, ids, mask, *, rows_axes=("model",),
-                          mesh=None, use_kernel: bool = True,
-                          interpret: bool = True):
+                          mesh=None, use_kernel: bool = True):
     """Multi-hot embedding bag under ``shard_map``: the (N, d) table
     row-sharded over ``rows_axes`` (layout: ``recsys_table_pspecs``), bags
     batch-sharded over the data axes; each device sums its owned slots with
@@ -573,10 +569,9 @@ def sharded_embedding_bag(table, ids, mask, *, rows_axes=("model",),
     mp = _axes_size(mesh, rows_ax) if mesh is not None else 1
     if mesh is None:
         if use_kernel:  # the custom_vjp wrapper: same kernel, differentiable
-            return embedding_bag_kernel(table, ids, mask, interpret)
+            return embedding_bag_kernel(table, ids, mask)
         return embedding_bag_ref(table, ids, mask)
     local = (embedding_bag_pallas if use_kernel else embedding_bag_ref)
-    kw = {"interpret": interpret} if use_kernel else {}
 
     dp = _dp_axes_of(mesh, rows_ax)
     batch_ax = _batch_entry(mesh, ids.shape[0], dp)
@@ -590,13 +585,13 @@ def sharded_embedding_bag(table, ids, mask, *, rows_axes=("model",),
         base = rows_shard_index(mesh, rows_ax) * rows_loc if mp > 1 else 0
         own = (ids_b >= base) & (ids_b < base + rows_loc)
         loc = jnp.clip(ids_b - base, 0, rows_loc - 1)
-        part = local(tab_loc, loc, mask_b & own, **kw)
+        part = local(tab_loc, loc, mask_b & own)
         return jax.lax.psum(part, rows_ax) if mp > 1 else part
 
     run_fwd = shard_map(
-        fwd_body, mesh,
+        fwd_body, mesh=mesh,
         in_specs=(P(rows_entry, None), P(batch_ax, None), P(batch_ax, None)),
-        out_specs=P(batch_ax, None), check_rep=False)
+        out_specs=P(batch_ax, None), check_vma=False)
 
     def bwd_body(g_loc, ids_b, mask_b):
         rows_loc = tab.shape[0] // mp
@@ -614,9 +609,9 @@ def sharded_embedding_bag(table, ids, mask, *, rows_axes=("model",),
         return d_loc.astype(g_loc.dtype)
 
     run_bwd = shard_map(
-        bwd_body, mesh,
+        bwd_body, mesh=mesh,
         in_specs=(P(batch_ax, None), P(batch_ax, None), P(batch_ax, None)),
-        out_specs=P(rows_entry, None), check_rep=False)
+        out_specs=P(rows_entry, None), check_vma=False)
 
     @jax.custom_vjp
     def bag(tab_p, ids_b, mask_b):
@@ -640,8 +635,7 @@ def sharded_embedding_bag(table, ids, mask, *, rows_axes=("model",),
 
 def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
                             causal: bool = True, bq: int = 128, bk: int = 128,
-                            head_axes=("model",), mesh=None,
-                            interpret: bool = True):
+                            head_axes=("model",), mesh=None):
     """Flash attention under ``shard_map``: batch over the data axes, query
     heads over ``head_axes`` — every (batch, head) pair computes wholly on
     one device, so there are no collectives and the result is bit-exact
@@ -661,8 +655,7 @@ def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
     del n_kv_heads  # derived from the shapes, as in the flat wrapper
     mesh = active_mesh(mesh)
     if mesh is None:
-        return flash_attention_kernel(q, k, v, causal=causal, bq=bq, bk=bk,
-                                      interpret=interpret)
+        return flash_attention_kernel(q, k, v, causal=causal, bq=bq, bk=bk)
 
     hq, hkv = q.shape[2], k.shape[2]
     if hkv != hq:  # GQA: expand KV to query heads before placing
@@ -686,13 +679,12 @@ def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
 
     def fwd_body(qb, kb, vb):
         return flash_attention_kernel(qb, kb, vb, causal=causal, bq=bq,
-                                      bk=bk, interpret=interpret)
+                                      bk=bk)
 
     def stats_body(qb, kb, vb):
         b, s, h, _ = qb.shape
         o, lse = flash_attention_fwd_stats(
-            _flat(qb), _flat(kb), _flat(vb), causal=causal, bq=bq_, bk=bk_,
-            interpret=interpret)
+            _flat(qb), _flat(kb), _flat(vb), causal=causal, bq=bq_, bk=bk_)
         return _unflat(o, b, s, h), lse.reshape(b, h, s)
 
     def bwd_body(qb, kb, vb, ob, lseb, dob):
@@ -700,17 +692,17 @@ def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
         dq, dk, dv = flash_attention_bwd(
             _flat(qb), _flat(kb), _flat(vb), _flat(ob),
             lseb.reshape(b * h, s), _flat(dob), causal=causal, bq=bq_,
-            bk=bk_, interpret=interpret)
+            bk=bk_)
         return (_unflat(dq, b, s, h), _unflat(dk, b, s, h),
                 _unflat(dv, b, s, h))
 
-    run_fwd = shard_map(fwd_body, mesh, in_specs=(spec,) * 3,
-                        out_specs=spec, check_rep=False)
-    run_stats = shard_map(stats_body, mesh, in_specs=(spec,) * 3,
-                          out_specs=(spec, lse_spec), check_rep=False)
-    run_bwd = shard_map(bwd_body, mesh,
+    run_fwd = shard_map(fwd_body, mesh=mesh, in_specs=(spec,) * 3,
+                        out_specs=spec, check_vma=False)
+    run_stats = shard_map(stats_body, mesh=mesh, in_specs=(spec,) * 3,
+                          out_specs=(spec, lse_spec), check_vma=False)
+    run_bwd = shard_map(bwd_body, mesh=mesh,
                         in_specs=(spec, spec, spec, spec, lse_spec, spec),
-                        out_specs=(spec, spec, spec), check_rep=False)
+                        out_specs=(spec, spec, spec), check_vma=False)
 
     @jax.custom_vjp
     def fa(qx, kx, vx):
@@ -731,8 +723,7 @@ def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
 # QAT mixed expectation (repro.kernels.mpe_qat)
 # ---------------------------------------------------------------------------
 
-def sharded_mixed_expectation(rows, probs, alpha, beta, bits, *, mesh=None,
-                              interpret: bool = True):
+def sharded_mixed_expectation(rows, probs, alpha, beta, bits, *, mesh=None):
     """Eq. (9) expectation-over-widths under ``shard_map``: rows split over
     *every* mesh axis (the op is row-parallel — the natural placement for
     the gathered rows of a batch-sharded train step); α/β replicated. No
@@ -742,8 +733,7 @@ def sharded_mixed_expectation(rows, probs, alpha, beta, bits, *, mesh=None,
 
     mesh = active_mesh(mesh)
     if mesh is None:
-        return mixed_expectation_kernel(rows, probs, alpha, beta, bits,
-                                        interpret)
+        return mixed_expectation_kernel(rows, probs, alpha, beta, bits)
 
     axes = tuple(mesh.axis_names)
     n = rows.shape[0]
@@ -751,12 +741,12 @@ def sharded_mixed_expectation(rows, probs, alpha, beta, bits, *, mesh=None,
     probs_p = pad_rows_to_shard(probs, mesh.size)
 
     def body(r, p, a, b_):
-        return mixed_expectation_kernel(r, p, a, b_, bits, interpret)
+        return mixed_expectation_kernel(r, p, a, b_, bits)
 
     out = shard_map(
-        body, mesh,
+        body, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None), P(None), P(None)),
-        out_specs=P(axes, None), check_rep=False)(rows_p, probs_p, alpha, beta)
+        out_specs=P(axes, None), check_vma=False)(rows_p, probs_p, alpha, beta)
     return out[:n]
 
 
@@ -872,10 +862,10 @@ def sharded_value_and_grad(loss_fn, mesh, *, rows_axes=("model",)):
         aux_specs = jax.tree.map(lambda s: P(*([None] * len(s.shape))),
                                  aux_sds)
         out_specs = ((P(), aux_specs), pspecs)
-        f = shard_map(inner, mesh,
+        f = shard_map(inner, mesh=mesh,
                       in_specs=(pspecs, replicate_like(buffers),
                                 replicate_like(state), batch_specs, P()),
-                      out_specs=out_specs, check_rep=False)
+                      out_specs=out_specs, check_vma=False)
         return f(params, buffers, state, batch, jnp.asarray(step))
 
     return vag

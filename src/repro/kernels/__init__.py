@@ -5,8 +5,10 @@ Each kernel package ships:
   ops.py    — jit'd public wrapper (+ custom_vjp where trainable)
   ref.py    — pure-jnp oracle; tests assert_allclose against it
 
-Validated with interpret=True on CPU (the container has no TPU); BlockSpecs
-are chosen for v5e VMEM/VREG geometry — see DESIGN.md §6.
+Interpret mode follows the backend (``backend.resolve_interpret``): Mosaic
+on a TPU, the Pallas interpreter elsewhere. Every block keeps its last two
+dims (8, 128)-divisible or equal to the array's, as Mosaic requires;
+``tests/test_tpu_compile.py`` compiles each kernel for a described v5e.
 """
 from repro.kernels.mpe_lookup.ops import (packed_lookup_kernel,
                                            packed_lookup_kernel_sharded)

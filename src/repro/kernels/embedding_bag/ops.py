@@ -3,24 +3,22 @@ cotangent into the touched rows — expressed with segment_sum (itself the
 TPU-native scatter) since the kernel's forward never materializes (B, L, d)."""
 from __future__ import annotations
 
-import functools
-
 import jax
 
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def embedding_bag_kernel(table, ids, mask, interpret=True):
-    return embedding_bag_pallas(table, ids, mask, interpret=interpret)
+@jax.custom_vjp
+def embedding_bag_kernel(table, ids, mask):
+    return embedding_bag_pallas(table, ids, mask)
 
 
-def _fwd(table, ids, mask, interpret):
-    out = embedding_bag_pallas(table, ids, mask, interpret=interpret)
+def _fwd(table, ids, mask):
+    out = embedding_bag_pallas(table, ids, mask)
     return out, (table.shape, ids, mask)
 
 
-def _bwd(interpret, res, g):
+def _bwd(res, g):
     table_shape, ids, mask = res
     b, l = ids.shape
     # d_table[row] += mask * g[bag] for every (bag, slot) pointing at row
@@ -35,7 +33,7 @@ embedding_bag_kernel.defvjp(_fwd, _bwd)
 
 
 def embedding_bag_kernel_sharded(table, ids, mask, *, rows_axes=("model",),
-                                 mesh=None, interpret: bool = True):
+                                 mesh=None):
     """Differentiable bag under ``shard_map``: table rows over ``rows_axes``,
     bags over the data axes, partial sums psum-merged; the backward pass is
     a ``custom_vjp`` that segment-sums each device's owned cotangent rows
@@ -47,4 +45,4 @@ def embedding_bag_kernel_sharded(table, ids, mask, *, rows_axes=("model",),
     ``repro.dist.shard``)."""
     from repro.dist.shard import sharded_embedding_bag
     return sharded_embedding_bag(table, ids, mask, rows_axes=rows_axes,
-                                 mesh=mesh, interpret=interpret)
+                                 mesh=mesh)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -139,7 +141,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, bq: int = 128,
-                              bk: int = 128, interpret: bool = True):
+                              bk: int = 128, interpret: bool | None = None):
     """Forward returning (o, lse) — the residuals the backward needs."""
     bh, s, hd = q.shape
     bq, bk = min(bq, s), min(bk, s)
@@ -167,14 +169,14 @@ def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
-                        bq: int = 128, bk: int = 128, interpret: bool = True):
+                        bq: int = 128, bk: int = 128, interpret: bool | None = None):
     """-> (dq, dk, dv). delta = rowsum(do ⊙ o) computed outside (cheap)."""
     bh, s, hd = q.shape
     bq, bk = min(bq, s), min(bk, s)
@@ -204,7 +206,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
             jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
             jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -212,7 +214,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128, interpret: bool | None = None):
     """q,k,v: (BH, S, hd) flattened batch·heads -> (BH, S, hd)."""
     bh, s, hd = q.shape
     bq = min(bq, s)
@@ -237,5 +239,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

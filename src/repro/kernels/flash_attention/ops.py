@@ -18,30 +18,27 @@ from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
                                                   flash_attention_pallas)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_flat(q, k, v, causal, bq, bk, interpret):
-    return flash_attention_pallas(q, k, v, causal=causal, bq=bq, bk=bk,
-                                  interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_flat(q, k, v, causal, bq, bk):
+    return flash_attention_pallas(q, k, v, causal=causal, bq=bq, bk=bk)
 
 
-def _flash_flat_fwd(q, k, v, causal, bq, bk, interpret):
-    o, lse = flash_attention_fwd_stats(q, k, v, causal=causal, bq=bq, bk=bk,
-                                       interpret=interpret)
+def _flash_flat_fwd(q, k, v, causal, bq, bk):
+    o, lse = flash_attention_fwd_stats(q, k, v, causal=causal, bq=bq, bk=bk)
     return o, (q, k, v, o, lse)
 
 
-def _flash_flat_bwd(causal, bq, bk, interpret, res, do):
+def _flash_flat_bwd(causal, bq, bk, res, do):
     q, k, v, o, lse = res
     return flash_attention_bwd(q, k, v, o, lse, do, causal=causal, bq=bq,
-                               bk=bk, interpret=interpret)
+                               bk=bk)
 
 
 _flash_flat.defvjp(_flash_flat_fwd, _flash_flat_bwd)
 
 
 def flash_attention_kernel(q, k, v, *, n_kv_heads: int | None = None,
-                           causal: bool = True, bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           causal: bool = True, bq: int = 128, bk: int = 128):
     """q: (B, S, Hq, hd); k,v: (B, S, Hkv, hd) -> (B, S, Hq, hd)."""
     b, s, hq, hd = q.shape
     hkv = k.shape[2]
@@ -51,14 +48,14 @@ def flash_attention_kernel(q, k, v, *, n_kv_heads: int | None = None,
     qf = jnp.moveaxis(q, 2, 1).reshape(b * hq, s, hd)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * hq, s, hd)
     vf = jnp.moveaxis(v, 2, 1).reshape(b * hq, s, hd)
-    of = _flash_flat(qf, kf, vf, causal, min(bq, s), min(bk, s), interpret)
+    of = _flash_flat(qf, kf, vf, causal, min(bq, s), min(bk, s))
     return jnp.moveaxis(of.reshape(b, hq, s, hd), 1, 2)
 
 
 def flash_attention_kernel_sharded(q, k, v, *, n_kv_heads: int | None = None,
                                    causal: bool = True, bq: int = 128,
                                    bk: int = 128, head_axes=("model",),
-                                   mesh=None, interpret: bool = True):
+                                   mesh=None):
     """Flash attention under ``shard_map``: batch over the data axes, heads
     over ``head_axes`` — collective-free and bit-exact vs the single-device
     kernel, forward and backward (a ``custom_vjp`` reruns the kernel with
@@ -69,5 +66,4 @@ def flash_attention_kernel_sharded(q, k, v, *, n_kv_heads: int | None = None,
     from repro.dist.shard import sharded_flash_attention
     return sharded_flash_attention(q, k, v, n_kv_heads=n_kv_heads,
                                    causal=causal, bq=bq, bk=bk,
-                                   head_axes=head_axes, mesh=mesh,
-                                   interpret=interpret)
+                                   head_axes=head_axes, mesh=mesh)

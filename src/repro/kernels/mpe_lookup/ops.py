@@ -13,7 +13,6 @@ from repro.kernels.mpe_lookup.kernel import packed_lookup_pallas
 
 def packed_lookup_kernel_sharded(table, meta, ids: jnp.ndarray, *,
                                  rows_axes=("model",), mesh=None,
-                                 interpret: bool = True,
                                  lookup_comms: str = "psum",
                                  bucket_capacity: int | None = None
                                  ) -> jnp.ndarray:
@@ -27,13 +26,11 @@ def packed_lookup_kernel_sharded(table, meta, ids: jnp.ndarray, *,
     from repro.dist.shard import sharded_packed_lookup
     return sharded_packed_lookup(table, meta, ids, rows_axes=rows_axes,
                                  mesh=mesh, use_kernel=True,
-                                 interpret=interpret,
                                  lookup_comms=lookup_comms,
                                  bucket_capacity=bucket_capacity)
 
 
-def packed_lookup_kernel(table, meta, ids: jnp.ndarray, *,
-                         interpret: bool = True) -> jnp.ndarray:
+def packed_lookup_kernel(table, meta, ids: jnp.ndarray) -> jnp.ndarray:
     bits = meta["bits"]
     d = meta["d"]
     flat = ids.reshape(-1)
@@ -46,6 +43,6 @@ def packed_lookup_kernel(table, meta, ids: jnp.ndarray, *,
         sub = table["subtables"][f"b{b}"]
         deq = packed_lookup_pallas(jnp.clip(lidx, 0, sub.shape[0] - 1), sub,
                                    table["alpha"][i], table["beta"],
-                                   b=b, d=d, interpret=interpret)
+                                   b=b, d=d)
         out = jnp.where((widx == i)[:, None], deq, out)
     return out.reshape(*ids.shape, d)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quantizer import int_bounds
+from repro.kernels.backend import resolve_interpret
 
 TILE_B = 256
 
@@ -101,7 +102,8 @@ def _pad(x, tile):
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def mixed_expectation_fwd(rows, probs, alpha, beta, *, bits, interpret=True):
+def mixed_expectation_fwd(rows, probs, alpha, beta, *, bits,
+                          interpret: bool | None = None):
     b0, d = rows.shape
     m = len(bits)
     rows_p, probs_p = _pad(rows, TILE_B), _pad(probs, TILE_B)
@@ -117,13 +119,14 @@ def mixed_expectation_fwd(rows, probs, alpha, beta, *, bits, interpret=True):
         ],
         out_specs=pl.BlockSpec((TILE_B, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(rows_p.shape, jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows_p, probs_p, alpha.reshape(1, m), beta.reshape(1, d))
     return out[:b0]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def mixed_expectation_bwd(rows, probs, alpha, beta, g, *, bits, interpret=True):
+def mixed_expectation_bwd(rows, probs, alpha, beta, g, *, bits,
+                          interpret: bool | None = None):
     b0, d = rows.shape
     m = len(bits)
     rows_p, probs_p, g_p = _pad(rows, TILE_B), _pad(probs, TILE_B), _pad(g, TILE_B)
@@ -150,6 +153,6 @@ def mixed_expectation_bwd(rows, probs, alpha, beta, g, *, bits, interpret=True):
             jax.ShapeDtypeStruct((1, m), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows_p, probs_p, alpha.reshape(1, m), beta.reshape(1, d), g_p)
     return drows[:b0], dprobs[:b0], dalpha.reshape(m), dbeta.reshape(d)

@@ -11,22 +11,20 @@ from repro.kernels.mpe_qat.kernel import (mixed_expectation_bwd,
                                           mixed_expectation_fwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def mixed_expectation_kernel(rows, probs, alpha, beta, bits, interpret=True):
-    return mixed_expectation_fwd(rows, probs, alpha, beta, bits=bits,
-                                 interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def mixed_expectation_kernel(rows, probs, alpha, beta, bits):
+    return mixed_expectation_fwd(rows, probs, alpha, beta, bits=bits)
 
 
-def _fwd(rows, probs, alpha, beta, bits, interpret):
-    out = mixed_expectation_fwd(rows, probs, alpha, beta, bits=bits,
-                                interpret=interpret)
+def _fwd(rows, probs, alpha, beta, bits):
+    out = mixed_expectation_fwd(rows, probs, alpha, beta, bits=bits)
     return out, (rows, probs, alpha, beta)
 
 
-def _bwd(bits, interpret, res, g):
+def _bwd(bits, res, g):
     rows, probs, alpha, beta = res
     drows, dprobs, dalpha, dbeta = mixed_expectation_bwd(
-        rows, probs, alpha, beta, g, bits=bits, interpret=interpret)
+        rows, probs, alpha, beta, g, bits=bits)
     return drows, dprobs, dalpha, dbeta
 
 
@@ -34,11 +32,11 @@ mixed_expectation_kernel.defvjp(_fwd, _bwd)
 
 
 def mixed_expectation_kernel_sharded(rows, probs, alpha, beta, bits, *,
-                                     mesh=None, interpret: bool = True):
+                                     mesh=None):
     """Forward Eq. (9) under ``shard_map``: rows split over every mesh axis
     (row-parallel, collective-free, bit-exact), padded up to the device
     count and unpadded after. Falls back to the fused kernel when no
     multi-device mesh is active (see ``repro.dist.shard``)."""
     from repro.dist.shard import sharded_mixed_expectation
     return sharded_mixed_expectation(rows, probs, alpha, beta, bits,
-                                     mesh=mesh, interpret=interpret)
+                                     mesh=mesh)

@@ -54,6 +54,7 @@ from repro.core.pipeline import run_mpe_pipeline
 from repro.data.synthetic import CTRSpec, SyntheticCTR
 from repro.dist.mesh import init_distributed, parse_mesh_flag
 from repro.embeddings.table import FieldSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dlrm import DLRMConfig
 from repro.serve import Engine
 from repro.train.optimizer import adam
@@ -64,10 +65,13 @@ DEFAULT_VOCABS = (2000, 1000, 1500, 800)
 
 def train_packed_dlrm(*, field_vocabs=DEFAULT_VOCABS, train_steps: int = 120,
                       train_batch: int = 1024, d_embed: int = 16,
-                      mlp_hidden=(64, 32), lam: float = 3e-5, seed: int = 0):
+                      mlp_hidden=(64, 32), lam: float = 3e-5, seed: int = 0,
+                      log_every: int = 100):
     """Quick MPE pipeline → (serve cfg, params, state, buffers, dataset
     spec, pipeline result). The packed table + retrained interaction net are
-    exactly what the engine binds at cell registration."""
+    exactly what the engine binds at cell registration; ``train_steps``
+    search steps are followed by as many retrain steps, and the losses at
+    every ``log_every``-th step land in ``result["history"]``."""
     spec = CTRSpec(field_vocabs=tuple(field_vocabs), batch_size=train_batch,
                    seed=seed)
     ds = SyntheticCTR(spec)
@@ -78,7 +82,8 @@ def train_packed_dlrm(*, field_vocabs=DEFAULT_VOCABS, train_steps: int = 120,
     res = run_mpe_pipeline(build, lambda s: ds.batch(s),
                            key=jax.random.PRNGKey(seed), mpe_cfg=MPEConfig(lam=lam),
                            optimizer=adam(1e-3), search_steps=train_steps,
-                           retrain_steps=train_steps, log_fn=lambda *a: None)
+                           retrain_steps=train_steps, log_fn=lambda *a: None,
+                           log_every=log_every)
 
     cfg = base._replace(compressor="packed",
                         comp_cfg={"bits": res["packed_meta"]["bits"],
@@ -281,6 +286,7 @@ def run_open_loop_mix(engine, make_ids, streams, *, seed: int = 0,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--batch", type=int, default=512,
                     help="rows per scoring request (any size; the batcher "

@@ -293,9 +293,11 @@ class EngineClient:
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.serve import build_engine, train_packed_dlrm
     from repro.serve import TenantQuota
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0,

@@ -20,6 +20,7 @@ from repro.core.mpe import MPEConfig
 from repro.core.pipeline import run_mpe_pipeline
 from repro.data.synthetic import CTRSpec, SyntheticCTR
 from repro.dist.mesh import init_distributed, parse_mesh_flag
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.dlrm import DLRMConfig
 from repro.train.loop import Trainer
 from repro.train.optimizer import adam
@@ -64,6 +65,7 @@ def _check_packed_lookup(res, fields, mesh, *, lookup_comms, bucket_capacity,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm-criteo")
     ap.add_argument("--backbone", default="dnn")

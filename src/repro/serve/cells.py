@@ -109,9 +109,10 @@ def packed_score_step(model, cfg, *, top_k: int | None = None,
     subtables row-sharded over ``rows_axes``. ``lookup_comms`` picks the
     merge collective — ``"psum"`` (dequantized partials) or ``"a2a"`` (the
     capacity-bucketed all-to-all of the packed words, ``bucket_capacity``
-    ids per bucket) — both bit-exact, so scores match the unsharded cell
-    either way. The post-lookup interaction net (``model.interact``) is
-    identical to the monolithic path. Degrades to the plain forward when
+    ids per bucket) — both bit-exact, so the embeddings match the unsharded
+    cell's either way. The post-lookup interaction net (``model.interact``)
+    is the monolithic path's; on a TPU the two programs may still order its
+    f32 sums differently, so scores can differ in the last bits. Degrades to the plain forward when
     compiled without a multi-device mesh."""
     if not shard_lookup:
         def serve_step(params, state, buffers, ids):

@@ -306,15 +306,13 @@ class Scheduler:
                 assembly_ms = (engine._clock() - t0) * 1e3
                 self._mark_dispatch(ready, chunk, cursor)
                 y, total_ms = engine._timed_call(reg, x)
+                # the lookup-split companion fails its chunk like the cell
+                # itself: a broken lookup executable must not pass unseen
+                lookup_ms = (None if reg.lookup is None
+                             else engine._timed_call(reg.lookup, x)[1])
             except Exception as err:   # fault injection: fail only this chunk
                 self._fail_chunk(ready, chunk, err, cursor, kind)
                 continue
-            lookup_ms = None
-            if reg.lookup is not None:
-                try:
-                    _, lookup_ms = engine._timed_call(reg.lookup, x)
-                except Exception:   # stats companion only — the chunk's
-                    lookup_ms = None    # results already computed fine
             engine.stats.record(reg.celldef.name, total_ms, lookup_ms,
                                 valid_rows=chunk.n_valid,
                                 capacity_rows=chunk.rows)

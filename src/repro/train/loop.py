@@ -38,6 +38,10 @@ class Trainer:
         self.ckpt_dir, self.ckpt_every, self.ckpt_keep = ckpt_dir, ckpt_every, ckpt_keep
         self.post_update = post_update
         self.step = 0
+        # one entry per log point: {"step", "loss", "metric", "grad_norm",
+        # "skipped", "wall_s"} — wall_s is the host time since the previous
+        # log point (or the start of ``run``), compile included
+        self.history: list[dict] = []
         opt_state = optimizer.init(params)
         ef_init, ef_apply = make_error_feedback_transform()
         self.grad_compression = grad_compression
@@ -59,10 +63,13 @@ class Trainer:
                 return jax.value_and_grad(self.loss_fn, has_aux=True)(
                     params, buffers, state, batch, step=step)
 
-        def train_step(carry, batch, step):
+        # buffers enter as an argument, not a closure: closed-over arrays
+        # would be embedded in the program as constants (at Criteo width the
+        # per-feature group map alone is 137 MB)
+        def train_step(carry, buffers, batch, step):
             params, state, opt_state = carry["params"], carry["state"], carry["opt"]
             (loss, (new_state, metric)), grads = value_and_grad(
-                params, self.buffers, state, batch, step=step)
+                params, buffers, state, batch, step=step)
             grads, gnorm = clip_by_global_norm(grads, clip_norm)
             ef_state = carry["ef"]
             if self.grad_compression:
@@ -118,21 +125,25 @@ class Trainer:
             from repro.cache.prefetch import PrefetchPipeline
             data_fn = (prefetch if isinstance(prefetch, PrefetchPipeline)
                        else PrefetchPipeline(data_fn))
-        t0 = time.time()
+        t0 = t_last = time.time()
         last = {}
         while self.step < n_steps:
             batch = data_fn(self.step)
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.carry, out = self._train_step(self.carry, batch,
+            self.carry, out = self._train_step(self.carry, self.buffers, batch,
                                                jnp.asarray(self.step))
             if self.post_update is not None:
                 self.carry["params"] = self.post_update(self.carry["params"])
             self.step += 1
             if log_every and self.step % log_every == 0:
                 last = {k: float(v) for k, v in out.items()}
+                now = time.time()
+                self.history.append(dict(last, step=self.step,
+                                         wall_s=now - t_last))
+                t_last = now
                 log_fn(f"step {self.step} loss {last['loss']:.5f} "
                        f"gnorm {last['grad_norm']:.3f} "
-                       f"({(time.time()-t0)/self.step*1e3:.1f} ms/step)")
+                       f"({(now - t0) / self.step * 1e3:.1f} ms/step)")
             if self.ckpt_dir and self.step % self.ckpt_every == 0:
                 self.save()
         if self.ckpt_dir:

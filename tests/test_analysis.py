@@ -52,8 +52,7 @@ def _celldef(**kw):
 # -- precision flow (PF1xx) -------------------------------------------------
 
 def test_pf101_float64_output():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jnp.ones((4,), jnp.float32))
     assert "PF101" in _codes(check_precision(jaxpr, "seeded"))
@@ -146,7 +145,6 @@ def test_sc202_nested_spec_trees():
 
 
 def test_sc204_shard_map_partial_without_psum():
-    from jax.experimental.shard_map import shard_map
     mesh = host_mesh()
 
     def partial_body(x):
@@ -157,12 +155,12 @@ def test_sc204_shard_map_partial_without_psum():
 
     x = jnp.ones((4, 8), jnp.float32)
     with use_mesh(mesh):
-        bad = jax.make_jaxpr(shard_map(
+        bad = jax.make_jaxpr(jax.shard_map(
             partial_body, mesh=mesh, in_specs=P("model", None),
-            out_specs=P(None), check_rep=False))(x)
-        good = jax.make_jaxpr(shard_map(
+            out_specs=P(None), check_vma=False))(x)
+        good = jax.make_jaxpr(jax.shard_map(
             merged_body, mesh=mesh, in_specs=P("model", None),
-            out_specs=P(None), check_rep=False))(x)
+            out_specs=P(None), check_vma=False))(x)
     assert _codes(check_shard_map_reductions(bad, "seeded")) == ["SC204"]
     assert check_shard_map_reductions(good, "clean") == []
 
@@ -268,7 +266,7 @@ def test_rl401_hand_rolled_pspec():
 
 
 def test_rl402_shard_map_outside_dist():
-    src = ("from jax.experimental.shard_map import shard_map\n"
+    src = ("from jax import shard_map\n"
            "f = shard_map(g, mesh=m)\n")
     assert _codes(lint_source(src, "src/repro/serve/foo.py")) == \
         ["RL402", "RL402"]

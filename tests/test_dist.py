@@ -25,6 +25,7 @@ from repro.dist import (current_dp_axes, dp_axes, host_mesh, lm_batch_pspecs,
                         packed_table_pspecs, recsys_table_pspecs,
                         replicate_like, shard_batch_dim,
                         tree_named_shardings, use_mesh)
+from repro.dist.sharding import normalize_entry
 
 SDS = jax.ShapeDtypeStruct
 
@@ -71,7 +72,9 @@ def test_lm_batch_and_cache_pspecs():
     assert cache["k"] == P(None, ("data",), "model", None, None)
     assert cache["v"] == cache["k"]
     assert cache["len"] == P()
-    assert cache["k"][1] == ("data",)  # cells.py derives scale pspecs from it
+    # cells.py derives scale pspecs from it; PartitionSpec stores a
+    # one-axis tuple as the bare axis name
+    assert normalize_entry(cache["k"][1]) == ("data",)
     long = lm_cache_pspecs(long_context=True, multi_pod=True)
     assert long["k"] == P(None, None, "model", None, None)  # B=1: no batch axis
 

@@ -543,6 +543,39 @@ def test_fault_injection_fails_only_affected_chunk(served):
     np.testing.assert_array_equal(engine.score(b, return_logits=True), want_b)
 
 
+def test_lookup_companion_fault_fails_its_chunk(served):
+    """The lookup-split companion is not a best-effort side call: when it
+    raises, the requests riding that chunk fail like a compute fault (a
+    smoke run can then never pass with a broken lookup executable), and the
+    other chunks still complete."""
+    ds_big = SyntheticCTR(served["spec"]._replace(batch_size=256))
+    ds_small = SyntheticCTR(served["spec"]._replace(batch_size=64))
+    a, b = ds_big.batch(21)["ids"], ds_small.batch(22)["ids"]
+    want_b = _twin(served).score(b, return_logits=True)
+
+    engine = _twin(served)
+    orig = engine._timed_call
+    faults = {"n": 0}
+
+    def broken_companion(reg, *request):
+        if reg.celldef.kind == "lookup" and faults["n"] == 0:
+            faults["n"] += 1
+            raise RuntimeError("companion fault")
+        return orig(reg, *request)
+
+    engine._timed_call = broken_companion
+    ta = engine.submit(a)             # bulk chunk: its companion raises
+    tb = engine.submit(b)             # p99 chunk
+    engine.drain()
+    engine._timed_call = orig
+
+    with pytest.raises(RequestFailedError, match="companion fault"):
+        engine.poll(ta)
+    np.testing.assert_array_equal(engine.poll(tb), want_b)
+    assert engine.rstats.failed == 1
+    assert len(engine.queue) == 0 and not engine.scheduler.busy
+
+
 def test_two_tenant_skewed_priority_open_loop(served):
     """``run_open_loop_mix``: a latency tenant (priority 0) and a bulk
     tenant (priority 1, quota-bounded) share the engine; both make

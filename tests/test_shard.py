@@ -331,7 +331,8 @@ def test_shard_suite_subprocess_fallback():
         [sys.executable, "-m", "pytest", "-q", "-m", "multidevice",
          "-p", "no:cacheprovider", os.path.join(here, "test_shard.py"),
          os.path.join(here, "test_shard_a2a.py"),
-         os.path.join(here, "test_dist.py")],
+         os.path.join(here, "test_dist.py"),
+         os.path.join(here, "test_chip_smoke.py")],
         env=subprocess_env_4dev(), capture_output=True, text=True,
         timeout=1800, cwd=os.path.join(here, os.pardir))
     assert proc.returncode == 0, \

@@ -276,7 +276,7 @@ def test_embedding_bag_grad_parity(mesh_shape, rng):
     mask = jnp.asarray(rng.random((B, L)) < 0.8)
 
     def loss_ref(t):
-        return jnp.sum(embedding_bag_kernel(t, ids, mask, True) ** 2)
+        return jnp.sum(embedding_bag_kernel(t, ids, mask) ** 2)
 
     lr, gr = jax.jit(jax.value_and_grad(loss_ref))(tab)
     mesh = _mesh(mesh_shape)
@@ -304,7 +304,7 @@ def test_embedding_bag_psum_tolerance(mesh_shape, rng):
     tab = jnp.asarray(rng.normal(size=(rows, d)).astype(np.float32))
     ids = jnp.asarray(rng.integers(0, rows, size=(B, L)).astype(np.int32))
     mask = jnp.asarray(rng.random((B, L)) < 0.9)
-    ref = np.asarray(embedding_bag_kernel(tab, ids, mask, True))
+    ref = np.asarray(embedding_bag_kernel(tab, ids, mask))
     with use_mesh(_mesh(mesh_shape)):
         got = np.asarray(jax.jit(lambda t, i, m: shard.sharded_embedding_bag(
             t, i, m))(tab, ids, mask))

@@ -1,0 +1,136 @@
+"""The main-path Pallas kernels and the ``dlrm-criteo`` packed score step
+compile for a TPU v5e chip that is described, not attached
+(``jax.experimental.topologies``): Mosaic refuses misaligned blocks and
+unsupported ops here, at no chip time. Nothing runs, so nothing is timed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and under pytest-xdist
+every worker imports this file while only the one given it loads the
+library. Keep every described-topology compile in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import packing
+from repro.core.inference import packed_specs
+from repro.core.mpe import MPEConfig
+
+BITS = MPEConfig().bits
+D = 16
+V5E_HBM_BYTES = 16e9          # one v5e chip (Google Cloud, "TPU v5e")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology executable cannot be read back without a chip
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b", [1, 2, 4, 6])
+def test_mpe_lookup_compiles(one_chip, b):
+    from repro.kernels.mpe_lookup.kernel import packed_lookup_pallas
+    w = packing.words_per_row(D, b)
+    compiled = jax.jit(lambda i, words, a, be: packed_lookup_pallas(
+        i, words, a, be, b=b, d=D, interpret=False)).lower(
+        _sds(one_chip, (4096,), jnp.int32),
+        _sds(one_chip, (1 << 16, w), jnp.uint32),
+        _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (D,), jnp.float32)).compile()
+    _assert_kernel(compiled)
+
+
+def _qat_args(one_chip, rows=2048 * 39):
+    return (_sds(one_chip, (rows, D), jnp.float32),
+            _sds(one_chip, (rows, len(BITS)), jnp.float32),
+            _sds(one_chip, (len(BITS),), jnp.float32),
+            _sds(one_chip, (D,), jnp.float32))
+
+
+def test_mpe_qat_fwd_compiles(one_chip):
+    from repro.kernels.mpe_qat.kernel import mixed_expectation_fwd
+    compiled = jax.jit(lambda r, p, a, be: mixed_expectation_fwd(
+        r, p, a, be, bits=BITS, interpret=False)).lower(
+        *_qat_args(one_chip)).compile()
+    _assert_kernel(compiled)
+
+
+def test_mpe_qat_bwd_compiles(one_chip):
+    from repro.kernels.mpe_qat.kernel import mixed_expectation_bwd
+    args = _qat_args(one_chip)
+    compiled = jax.jit(lambda r, p, a, be, g: mixed_expectation_bwd(
+        r, p, a, be, g, bits=BITS, interpret=False)).lower(
+        *args, args[0]).compile()
+    _assert_kernel(compiled)
+
+
+def test_embedding_bag_compiles(one_chip):
+    from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
+    compiled = jax.jit(lambda t, i, m: embedding_bag_pallas(
+        t, i, m, interpret=False)).lower(
+        _sds(one_chip, (1 << 16, D), jnp.float32),
+        _sds(one_chip, (512, 8), jnp.int32),
+        _sds(one_chip, (512, 8), jnp.bool_)).compile()
+    _assert_kernel(compiled)
+
+
+def test_dlrm_criteo_packed_score_step_fits_one_chip(one_chip):
+    """The serve_p99 step of the full-width table (~34M rows, MLP
+    1024-512-256) at 512 rows, on ``packed_specs`` shapes."""
+    from repro.configs.dlrm_criteo import make_config
+    from repro.models.dlrm import DLRM
+    from repro.nn.mlp import MLP
+    from repro.serve.cells import packed_score_step
+
+    cfg = make_config(reduced=False)
+    n = sum(f.vocab for f in cfg.fields)
+    n_fields = len(cfg.fields)
+    serve_cfg = cfg._replace(compressor="packed",
+                             comp_cfg={"bits": BITS, "d": D, "n": n})
+    hist = (0.1, 0.1, 0.15, 0.15, 0.2, 0.15, 0.15)
+    mlp = jax.eval_shape(lambda k: MLP.init(k, n_fields * D, cfg.mlp_hidden,
+                                            d_out=1, use_batchnorm=True),
+                         jax.random.PRNGKey(0))
+    params = {"embedding": packed_specs(n, D, MPEConfig(), hist), "mlp": mlp}
+    state = {"mlp": jax.eval_shape(lambda: MLP.init_state(
+        cfg.mlp_hidden, use_batchnorm=True))}
+    buffers = {"embedding": {},
+               "offsets": jax.ShapeDtypeStruct((n_fields,), jnp.int32)}
+
+    def place(tree):
+        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    compiled = jax.jit(packed_score_step(DLRM, serve_cfg)).lower(
+        place(params), place(state), place(buffers),
+        _sds(one_chip, (512, n_fields), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    table_bytes = sum(np.prod(s.shape) * 4 for s in
+                      jax.tree.leaves(params["embedding"]))
+    assert m.argument_size_in_bytes >= table_bytes
+    assert peak < V5E_HBM_BYTES, f"{peak / 1e9:.2f} GB"
