@@ -70,27 +70,35 @@ class MPESearchEmbedding:
 
     @staticmethod
     def lookup(params, buffers, ids: jnp.ndarray, cfg: MPEConfig) -> jnp.ndarray:
-        """ids: int32 of any shape -> (*ids.shape, d) mixed-precision embeddings."""
-        rows = jnp.take(params["emb"], ids, axis=0)
-        # §Perf: keep gathered rows batch-sharded — without the pin, GSPMD
-        # may replicate the (B, F, d) gather output to every device
-        # (EXPERIMENTS.md §Perf wide-deep it1). No-op outside a mesh.
+        """ids: int32 of any shape -> (*ids.shape, d) mixed-precision embeddings.
+
+        Named scopes tag the device ops: ``embed_gather`` (the row, group and
+        probability gathers; under ``transpose(...)`` the table gradient's
+        zero-fill and scatter-add) and ``embed_quantize`` (Eq. 9 and its
+        straight-through backward)."""
         from repro.dist.sharding import shard_batch_dim
-        rows = shard_batch_dim(rows)
-        p = MPESearchEmbedding.probabilities(params, cfg)        # (g, m)
-        gid = jnp.take(buffers["group_of_feature"], ids, axis=0)
-        probs = jnp.take(p, gid, axis=0)                          # (*ids, m)
-        probs = shard_batch_dim(probs)
-        return quantizer.mixed_expectation(rows, probs, params["alpha"],
-                                           params["beta"], cfg.bits)
+        with jax.named_scope("embed_gather"):
+            rows = jnp.take(params["emb"], ids, axis=0)
+            # §Perf: keep gathered rows batch-sharded — without the pin, GSPMD
+            # may replicate the (B, F, d) gather output to every device
+            # (EXPERIMENTS.md §Perf wide-deep it1). No-op outside a mesh.
+            rows = shard_batch_dim(rows)
+            p = MPESearchEmbedding.probabilities(params, cfg)    # (g, m)
+            gid = jnp.take(buffers["group_of_feature"], ids, axis=0)
+            probs = jnp.take(p, gid, axis=0)                      # (*ids, m)
+            probs = shard_batch_dim(probs)
+        with jax.named_scope("embed_quantize"):
+            return quantizer.mixed_expectation(rows, probs, params["alpha"],
+                                               params["beta"], cfg.bits)
 
     @staticmethod
     def reg_loss(params, buffers, cfg: MPEConfig) -> jnp.ndarray:
         """Eq. (10): Σ_j (1/s_j) Σ_i b_i p_i^j  (caller multiplies by λ)."""
-        p = MPESearchEmbedding.probabilities(params, cfg)         # (g, m)
-        bits = jnp.asarray(cfg.bits, jnp.float32)
-        per_group = p @ bits                                      # (g,)
-        return jnp.sum(per_group / buffers["freq_sum"])
+        with jax.named_scope("embed_quantize"):
+            p = MPESearchEmbedding.probabilities(params, cfg)     # (g, m)
+            bits = jnp.asarray(cfg.bits, jnp.float32)
+            per_group = p @ bits                                  # (g,)
+            return jnp.sum(per_group / buffers["freq_sum"])
 
     @staticmethod
     def expected_bits(params, buffers, cfg: MPEConfig) -> jnp.ndarray:

@@ -99,13 +99,15 @@ class DLRM:
     @staticmethod
     def apply(params, buffers, state, batch, cfg: DLRMConfig, *,
               train: bool = False, step=None):
-        """Returns (logits (B,), new_state, reg_loss)."""
+        """Returns (logits (B,), new_state, reg_loss). The interaction
+        branch and the MLP head run under the ``tower`` named scope."""
         comp = get_compressor(cfg.compressor)
         gids = batch["ids"] + buffers["offsets"][None, :]
         emb = comp.lookup(params["embedding"], buffers["embedding"], gids,
                           cfg.comp_cfg, train=train, step=step)  # (B, F, d)
-        logit, new_state = DLRM.interact(params, state, emb, gids, cfg,
-                                         train=train)
+        with jax.named_scope("tower"):
+            logit, new_state = DLRM.interact(params, state, emb, gids, cfg,
+                                             train=train)
         reg = comp.reg_loss(params["embedding"], buffers["embedding"], cfg.comp_cfg)
         return logit, new_state, reg
 
@@ -114,7 +116,8 @@ class DLRM:
                 lam: float = 0.0, train: bool = True, step=None):
         logits, new_state, reg = DLRM.apply(params, buffers, state, batch, cfg,
                                             train=train, step=step)
-        labels = batch["label"].astype(jnp.float32)
-        ce = jnp.mean(
-            jnp.maximum(logits, 0) - logits * labels + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+        with jax.named_scope("tower"):
+            labels = batch["label"].astype(jnp.float32)
+            ce = jnp.mean(jnp.maximum(logits, 0) - logits * labels
+                          + jnp.log1p(jnp.exp(-jnp.abs(logits))))
         return ce + lam * reg, (new_state, ce)

@@ -6,6 +6,9 @@ Works with every model in the zoo through a uniform loss signature:
 
 Features (DESIGN.md §5):
   - jitted train step with grad clipping;
+  - named scopes ``clip`` and ``update`` over the step's device ops, host
+    spans ``trainer.step`` (a ``StepTraceAnnotation``), ``trainer.data``,
+    ``trainer.stage`` and ``trainer.dispatch`` on the profiler's clock;
   - NaN/inf guard: non-finite grads skip the update (params/opt state kept);
   - checkpoint every N steps (atomic, keep-k, async), restore-on-start;
   - optional compressor post-update hook (ALPT grid projection);
@@ -70,24 +73,37 @@ class Trainer:
             params, state, opt_state = carry["params"], carry["state"], carry["opt"]
             (loss, (new_state, metric)), grads = value_and_grad(
                 params, buffers, state, batch, step=step)
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-            ef_state = carry["ef"]
-            if self.grad_compression:
-                grads, ef_state = ef_apply(grads, ef_state)
-            updates, new_opt = self.optimizer.update(grads, opt_state, params)
-            new_params = apply_updates(params, updates)
-            # NaN guard: skip the whole update on non-finite grads
-            ok = jnp.isfinite(gnorm) & jnp.isfinite(loss)
-            new_params = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                      new_params, params)
-            new_opt = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                   new_opt, opt_state)
+            with jax.named_scope("clip"):
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            # Adam and the guard share one scope: XLA fuses them, and a
+            # fusion carries its root's scope
+            with jax.named_scope("update"):
+                ef_state = carry["ef"]
+                if self.grad_compression:
+                    grads, ef_state = ef_apply(grads, ef_state)
+                updates, new_opt = self.optimizer.update(grads, opt_state,
+                                                         params)
+                new_params = apply_updates(params, updates)
+                # NaN guard: skip the whole update on non-finite grads
+                ok = jnp.isfinite(gnorm) & jnp.isfinite(loss)
+                new_params = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                          new_params, params)
+                new_opt = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                       new_opt, opt_state)
             new_carry = {"params": new_params, "state": new_state,
                          "opt": new_opt, "ef": ef_state}
             return new_carry, {"loss": loss, "metric": metric,
                                "grad_norm": gnorm, "skipped": ~ok}
 
         self._train_step = jax.jit(train_step, donate_argnums=(0,) if donate else ())
+
+    def compiled_step(self, batch):
+        """The executable ``run`` calls for a batch shaped like ``batch``.
+        Its ``as_text()`` names every device op with the scope it came from
+        (``op_name`` metadata)."""
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        return self._train_step.lower(self.carry, self.buffers, batch,
+                                      jnp.asarray(self.step)).compile()
 
     # -- fault tolerance ----------------------------------------------------
     def restore(self) -> bool:
@@ -125,27 +141,33 @@ class Trainer:
             from repro.cache.prefetch import PrefetchPipeline
             data_fn = (prefetch if isinstance(prefetch, PrefetchPipeline)
                        else PrefetchPipeline(data_fn))
-        t0 = t_last = time.time()
+        t0 = t_last = time.perf_counter()
+        start_step = self.step
         last = {}
         while self.step < n_steps:
-            batch = data_fn(self.step)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.carry, out = self._train_step(self.carry, self.buffers, batch,
-                                               jnp.asarray(self.step))
-            if self.post_update is not None:
-                self.carry["params"] = self.post_update(self.carry["params"])
-            self.step += 1
-            if log_every and self.step % log_every == 0:
-                last = {k: float(v) for k, v in out.items()}
-                now = time.time()
-                self.history.append(dict(last, step=self.step,
-                                         wall_s=now - t_last))
-                t_last = now
-                log_fn(f"step {self.step} loss {last['loss']:.5f} "
-                       f"gnorm {last['grad_norm']:.3f} "
-                       f"({(now - t0) / self.step * 1e3:.1f} ms/step)")
-            if self.ckpt_dir and self.step % self.ckpt_every == 0:
-                self.save()
+            with jax.profiler.StepTraceAnnotation("trainer.step",
+                                                  step_num=self.step):
+                with jax.profiler.TraceAnnotation("trainer.data"):
+                    batch = data_fn(self.step)
+                with jax.profiler.TraceAnnotation("trainer.stage"):
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with jax.profiler.TraceAnnotation("trainer.dispatch"):
+                    self.carry, out = self._train_step(
+                        self.carry, self.buffers, batch, jnp.asarray(self.step))
+                if self.post_update is not None:
+                    self.carry["params"] = self.post_update(self.carry["params"])
+                self.step += 1
+                if log_every and self.step % log_every == 0:
+                    last = {k: float(v) for k, v in out.items()}
+                    now = time.perf_counter()
+                    self.history.append(dict(last, step=self.step,
+                                             wall_s=now - t_last))
+                    t_last = now
+                    ms = (now - t0) / (self.step - start_step) * 1e3
+                    log_fn(f"step {self.step} loss {last['loss']:.5f} "
+                           f"gnorm {last['grad_norm']:.3f} ({ms:.1f} ms/step)")
+                if self.ckpt_dir and self.step % self.ckpt_every == 0:
+                    self.save()
         if self.ckpt_dir:
             self.save(blocking=True)
         return last

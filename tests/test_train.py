@@ -1,27 +1,41 @@
-"""Train-loop behaviour: resume bit-exactness, NaN guard, grad compression."""
+"""Train-loop behaviour: resume bit-exactness, NaN guard, grad compression,
+the step's named scopes and the loop's host spans."""
+import glob
+import os
 import shutil
 import tempfile
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.data.synthetic import CTRSpec, SyntheticCTR
 from repro.embeddings.table import FieldSpec
 from repro.models.dlrm import DLRMConfig
 from repro.train.compression import (int8_compress, int8_decompress,
                                      rowsparse_compress, rowsparse_decompress)
+from repro.train import loop
 from repro.train.loop import Trainer
 from repro.train.optimizer import adam, warmup_cosine
 from repro.zoo import dlrm_builder
 
 
-def _tiny_setup():
+def _tiny_setup(lam: float = 0.0):
     spec = CTRSpec(field_vocabs=(300, 200), batch_size=256, seed=0)
     ds = SyntheticCTR(spec)
     fields = tuple(FieldSpec(f"f{i}", v) for i, v in enumerate(spec.field_vocabs))
     base = DLRMConfig(fields=fields, d_embed=8, mlp_hidden=(16,), backbone="dnn")
-    return ds, dlrm_builder(base, ds.expected_frequencies())
+    return ds, dlrm_builder(base, ds.expected_frequencies(), lam=lam)
+
+
+def _mpe_trainer():
+    """A tiny DLRM in its MPE search phase, as the benchmark's cell runs."""
+    ds, build = _tiny_setup(lam=1e-5)
+    b = build(jax.random.PRNGKey(0), "mpe_search", {})
+    return ds, Trainer(b["loss_fn"], b["params"], b["buffers"], b["state"],
+                       adam(1e-3))
 
 
 def test_checkpoint_resume_bit_exact():
@@ -100,3 +114,54 @@ def test_lr_schedule():
     assert float(fn(jnp.asarray(0))) == 0.0
     assert abs(float(fn(jnp.asarray(10))) - 1e-3) < 1e-9
     assert float(fn(jnp.asarray(100))) < 1e-5
+
+
+@pytest.fixture(scope="module")
+def step_text():
+    ds, tr = _mpe_trainer()
+    return tr.compiled_step(ds.batch(0)).as_text()
+
+
+@pytest.mark.parametrize("scope", [
+    "jvp(embed_gather)", "transpose(jvp(embed_gather))", "jvp(embed_quantize)",
+    "transpose(jvp(embed_quantize))", "jvp(tower)", "transpose(jvp(tower))",
+    "clip", "update"])
+def test_compiled_step_names_every_scope(step_text, scope):
+    """The executable ``run`` calls tags its ops with the step's parts; the
+    transpose of the gather is the table gradient."""
+    assert f'op_name="jit(train_step)/{scope}/' in step_text
+
+
+def test_run_profile_holds_the_trainer_spans(tmp_path):
+    from jax.profiler import ProfileData
+    ds, tr = _mpe_trainer()
+    tr.run(lambda s: ds.batch(s), 1, log_every=0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tr.run(lambda s: ds.batch(s), 4, log_every=0)
+        jax.block_until_ready(tr.carry)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    names = [e.name for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events]
+    for span in ("trainer.step", "trainer.data", "trainer.stage",
+                 "trainer.dispatch"):
+        assert names.count(span) == 3, span
+
+
+def test_run_logs_ms_per_step_of_this_run(monkeypatch):
+    """ms/step divides this run's time by this run's steps, not by every
+    step since the trainer was made."""
+    ds, tr = _mpe_trainer()
+    tr.run(lambda s: ds.batch(s), 10, log_every=0)
+    ticks = iter(range(100))
+    monkeypatch.setattr(loop, "time", types.SimpleNamespace(
+        perf_counter=lambda: float(next(ticks))))
+    lines = []
+    tr.run(lambda s: ds.batch(s), 12, log_every=2, log_fn=lines.append)
+    # one tick to start, one at the log point after two steps: 0.5 s a step
+    assert lines[-1].endswith("(500.0 ms/step)")
+    assert tr.history[-1]["wall_s"] == 1.0
