@@ -9,7 +9,13 @@ Features (DESIGN.md §5):
   - named scopes ``clip`` and ``update`` over the step's device ops, host
     spans ``trainer.step`` (a ``StepTraceAnnotation``), ``trainer.data``,
     ``trainer.stage`` and ``trainer.dispatch`` on the profiler's clock;
-  - NaN/inf guard: non-finite grads skip the update (params/opt state kept);
+  - NaN/inf guard: non-finite grads skip the update (params/opt state
+    kept). The guard is the optimizer's ``ok`` argument, folded into its
+    per-step scalars (zero step size, moment decays (1, 0), gradient read
+    as zero): selecting per element between the new and the old params
+    and moments split a table's update into three passes (θ, μ, ν each
+    streaming the gradient), where the folded guard lets XLA write all
+    three in one;
   - checkpoint every N steps (atomic, keep-k, async), restore-on-start;
   - optional compressor post-update hook (ALPT grid projection);
   - optional int8 error-feedback gradient compression (cross-pod simulation);
@@ -75,21 +81,15 @@ class Trainer:
                 params, buffers, state, batch, step=step)
             with jax.named_scope("clip"):
                 grads, gnorm = clip_by_global_norm(grads, clip_norm)
-            # Adam and the guard share one scope: XLA fuses them, and a
-            # fusion carries its root's scope
             with jax.named_scope("update"):
                 ef_state = carry["ef"]
                 if self.grad_compression:
                     grads, ef_state = ef_apply(grads, ef_state)
-                updates, new_opt = self.optimizer.update(grads, opt_state,
-                                                         params)
-                new_params = apply_updates(params, updates)
-                # NaN guard: skip the whole update on non-finite grads
+                # NaN guard: non-finite grads or loss skip the whole update
                 ok = jnp.isfinite(gnorm) & jnp.isfinite(loss)
-                new_params = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                          new_params, params)
-                new_opt = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                       new_opt, opt_state)
+                updates, new_opt = self.optimizer.update(grads, opt_state,
+                                                         params, ok=ok)
+                new_params = apply_updates(params, updates)
             new_carry = {"params": new_params, "state": new_state,
                          "opt": new_opt, "ef": ef_state}
             return new_carry, {"loss": loss, "metric": metric,

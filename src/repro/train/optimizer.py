@@ -7,6 +7,16 @@ API mirrors optax's GradientTransformation so call-sites read familiarly:
     updates, opt_state = opt.update(grads, opt_state, params)
     params = apply_updates(params, updates)
 
+``update(grads, state, params, ok=True)``: a traced boolean ``ok`` guards
+the step without selecting between new and old values. When ``ok`` is
+false the gradient is read as zero, the moment coefficients become
+(1, 0), the step size (and the weight decay that follows it) becomes 0 and
+``step`` stays, so θ + (−0·u), 1·μ + 0·0 and 1·ν + 0·0 leave the
+parameters and the state unchanged whatever non-finite values the gradient
+holds. The guard rides on per-step scalars, so XLA can still update θ, μ
+and ν of a leaf in one pass. The default ``ok=True`` is the unguarded
+step: under jit its selects fold away.
+
 Paper recipe (§5.1.5): Adam, lr=1e-3, weight decay in {0, 3e-6} depending on
 the dataset.
 """
@@ -20,7 +30,7 @@ import jax.numpy as jnp
 
 class GradientTransformation(NamedTuple):
     init: Callable
-    update: Callable  # (grads, state, params) -> (updates, state)
+    update: Callable  # (grads, state, params, ok=True) -> (updates, state)
 
 
 def adam(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
@@ -50,16 +60,19 @@ def adam(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
             "nu": jax.tree.map(lambda p: _stored(jnp.zeros_like(p)), params),
         }
 
-    def update(grads, state, params):
+    def update(grads, state, params, ok=True):
+        # lr and the bias corrections read the step being taken even when it
+        # is skipped: at step 0 a kept step would make bc1 = 0 and 0/0 = nan
         step = state["step"] + 1
-        lr_t = lr_fn(step)
+        grads, lr_t, (c1, d1), (c2, d2) = _guard(ok, grads, lr_fn(step),
+                                                 (b1, 1 - b1), (b2, 1 - b2))
         mu = jax.tree.map(
-            lambda m, g: _stored(b1 * m.astype(jnp.float32)
-                                 + (1 - b1) * g.astype(jnp.float32)),
+            lambda m, g: _stored(c1 * m.astype(jnp.float32)
+                                 + d1 * g.astype(jnp.float32)),
             state["mu"], grads)
         nu = jax.tree.map(
-            lambda v, g: _stored(b2 * v.astype(jnp.float32)
-                                 + (1 - b2) * jnp.square(g.astype(jnp.float32))),
+            lambda v, g: _stored(c2 * v.astype(jnp.float32)
+                                 + d2 * jnp.square(g.astype(jnp.float32))),
             state["nu"], grads)
         bc1 = 1 - b1 ** step.astype(jnp.float32)
         bc2 = 1 - b2 ** step.astype(jnp.float32)
@@ -72,7 +85,8 @@ def adam(lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
             return u
 
         updates = jax.tree.map(_upd, mu, nu, params)
-        return updates, {"step": step, "mu": mu, "nu": nu}
+        return updates, {"step": jnp.where(ok, step, state["step"]),
+                         "mu": mu, "nu": nu}
 
     return GradientTransformation(init, update)
 
@@ -86,18 +100,30 @@ def sgd(lr, momentum: float = 0.0) -> GradientTransformation:
             state["mom"] = jax.tree.map(jnp.zeros_like, params)
         return state
 
-    def update(grads, state, params):
+    def update(grads, state, params, ok=True):
         del params
         step = state["step"] + 1
-        lr_t = lr_fn(step)
+        grads, lr_t, (c, d) = _guard(ok, grads, lr_fn(step), (momentum, 1.0))
+        new_step = jnp.where(ok, step, state["step"])
         if momentum:
-            mom = jax.tree.map(lambda m, g: momentum * m + g, state["mom"], grads)
+            mom = jax.tree.map(lambda m, g: c * m + d * g, state["mom"], grads)
             updates = jax.tree.map(lambda m: -lr_t * m, mom)
-            return updates, {"step": step, "mom": mom}
+            return updates, {"step": new_step, "mom": mom}
         updates = jax.tree.map(lambda g: -lr_t * g, grads)
-        return updates, {"step": step}
+        return updates, {"step": new_step}
 
     return GradientTransformation(init, update)
+
+
+def _guard(ok, grads, lr_t, *decays):
+    """The per-step scalars of a step that ``ok`` may skip: the gradient
+    read as zero, the step size 0 and each (decay, 1 − decay) pair of a
+    moment (1, 0) when ``ok`` is false; as given when it is true."""
+    grads = jax.tree.map(lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+    lr_t = jnp.where(ok, lr_t, 0.0)
+    decays = tuple((jnp.where(ok, c, 1.0), jnp.where(ok, d, 0.0))
+                   for c, d in decays)
+    return (grads, lr_t, *decays)
 
 
 def apply_updates(params, updates):

@@ -134,3 +134,38 @@ def test_dlrm_criteo_packed_score_step_fits_one_chip(one_chip):
                       jax.tree.leaves(params["embedding"]))
     assert m.argument_size_in_bytes >= table_bytes
     assert peak < V5E_HBM_BYTES, f"{peak / 1e9:.2f} GB"
+
+
+def test_trainer_updates_the_criteo_table_in_one_fusion(one_chip):
+    """The NaN guard rides on the optimizer's per-step scalars, so Adam
+    writes the full ``dlrm-criteo`` table's θ, μ and ν in one fusion. An
+    element-wise select between new and old values splits it into three
+    (θ, μ and ν each streaming the gradient), but only at this size: at
+    1M rows XLA fuses either way."""
+    from repro.train.loop import Trainer
+    from repro.train.optimizer import adam
+
+    rows, batch, n_fields = 33_775_889, 2048, 39
+
+    def loss_fn(params, buffers, state, b, *, step=None):
+        logit = jnp.sum(params["table"][b["ids"]], axis=(1, 2)) * params["w"][0]
+        return jnp.mean(jnp.square(logit)), (state, jnp.mean(logit))
+
+    small = {"table": jnp.zeros((64, D)), "w": jnp.ones((D,))}
+    tr = Trainer(loss_fn, small, {}, {}, adam(1e-3))
+
+    def place(x):
+        shape = (rows, D) if x.shape == (64, D) else x.shape
+        return _sds(one_chip, shape, x.dtype)
+
+    compiled = tr._train_step.lower(
+        jax.tree.map(place, tr.carry), {},
+        {"ids": _sds(one_chip, (batch, n_fields), jnp.int32)},
+        _sds(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    table = f"f32[{rows},{D}]"
+    outputs = [line.split(" fusion(")[0].count(table)
+               for line in text[text.index("\nENTRY"):].splitlines()
+               if " fusion(" in line and 'op_name="jit(train_step)/update/'
+               in line and table in line.split(" fusion(")[0]]
+    assert outputs == [3], outputs

@@ -73,20 +73,41 @@ def test_nan_guard_skips_update():
         return loss + batch["nan"], aux
 
     tr = Trainer(loss_fn, b["params"], b["buffers"], b["state"], adam(1e-3))
-    before = np.asarray(jax.tree.leaves(tr.params)[0]).copy()
 
     def data_fn(step):
         d = ds.batch(step)
-        d["nan"] = np.float32("nan") if step == 0 else np.float32(0.0)
+        d["nan"] = np.float32("nan") if step in (0, 2) else np.float32(0.0)
         return d
 
-    tr.run(data_fn, 1, log_every=0)
-    after = np.asarray(jax.tree.leaves(tr.params)[0])
-    np.testing.assert_array_equal(before, after)  # step skipped
+    def snapshot():
+        return jax.tree.map(lambda x: np.asarray(x).copy(),
+                            (tr.params, tr.carry["opt"]))
 
-    tr.run(data_fn, 2, log_every=0)  # clean step applies
+    def assert_kept(before):
+        after = snapshot()
+        assert jax.tree.structure(after) == jax.tree.structure(before)
+        for a, c in zip(jax.tree.leaves(after), jax.tree.leaves(before)):
+            np.testing.assert_array_equal(a, c)
+
+    # the first step is poisoned: the moments are zero and the bias
+    # correction reads step 1, so a skip must not divide 0 by 0
+    init = snapshot()
+    tr.run(data_fn, 1, log_every=0)
+    assert_kept(init)
+    assert int(init[1]["step"]) == 0
+
+    tr.run(data_fn, 2, log_every=0)  # a clean step, so the moments are set
+    before = snapshot()
+    assert int(before[1]["step"]) == 1
+    assert np.abs(jax.tree.leaves(before[1]["mu"])[0]).max() > 0
+
+    tr.run(data_fn, 3, log_every=0)  # poisoned again, once the moments are set
+    assert_kept(before)
+
+    tr.run(data_fn, 4, log_every=0)  # clean step applies
     after2 = np.asarray(jax.tree.leaves(tr.params)[0])
-    assert np.abs(after2 - before).max() > 0
+    assert np.abs(after2 - jax.tree.leaves(before[0])[0]).max() > 0
+    assert int(tr.carry["opt"]["step"]) == 2
 
 
 def test_int8_error_feedback_telescopes(rng):
