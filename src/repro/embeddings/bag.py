@@ -36,6 +36,17 @@ def reduce_bag(rows: jnp.ndarray, mask: jnp.ndarray | None, *, combine: str = "s
     raise ValueError(f"unknown combine {combine}")
 
 
+def pool_fields(rows: jnp.ndarray, sizes) -> jnp.ndarray:
+    """Sum pooling of fixed-size bags laid side by side: rows (B, ΣL_f, d),
+    field f's ``sizes[f]`` slots in order -> (B, F, d). Each field is summed
+    over its own slots, with no padding to the longest bag."""
+    out, s = [], 0
+    for n in sizes:
+        out.append(rows[:, s] if n == 1 else jnp.sum(rows[:, s:s + n], axis=1))
+        s += n
+    return jnp.stack(out, axis=1)
+
+
 def ragged_embedding_bag(table: jnp.ndarray, flat_ids: jnp.ndarray,
                          segment_ids: jnp.ndarray, num_bags: int,
                          *, combine: str = "sum") -> jnp.ndarray:

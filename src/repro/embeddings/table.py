@@ -24,6 +24,15 @@ def field_offsets(fields: Sequence[FieldSpec]) -> np.ndarray:
     return np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
 
 
+def slot_offsets(fields: Sequence[FieldSpec]) -> np.ndarray:
+    """(ΣL_f,) each id slot's field offset: a multi-hot field's
+    ``multiplicity`` slots sit side by side in the field's order, so its
+    offset repeats once per slot. With one slot per field this is
+    ``field_offsets``."""
+    return np.repeat(field_offsets(fields),
+                     [f.multiplicity for f in fields]).astype(np.int32)
+
+
 def total_vocab(fields: Sequence[FieldSpec]) -> int:
     return int(sum(f.vocab for f in fields))
 

@@ -2,7 +2,8 @@
 
 DNN = MLP only; DCN adds a cross network [arXiv:1708.05123]; DeepFM adds a
 factorization machine [Rendle ICDM'10]; IPNN adds an inner-product layer
-[arXiv:1611.00144].
+[arXiv:1611.00144]; DCN-v2 stacks low-rank cross layers [arXiv:2008.13535]
+under the MLP.
 """
 from __future__ import annotations
 
@@ -44,4 +45,32 @@ class CrossNetwork:
         x = x0
         for w, b in zip(params["w"], params["b"]):
             x = x0 * (x @ w)[:, None] + b + x
+        return x
+
+
+class LowRankCrossNet:
+    """DCN-v2 cross layers in low-rank form (arXiv:2008.13535, Eq. 1 with
+    the factorization of §5), as MLPerf's DLRM-DCNv2 runs them:
+    x_{l+1} = x0 ⊙ (W_l (V_l x_l) + b_l) + x_l, with V_l: r×d (no bias) and
+    W_l: d×r. Kernels are stored for ``x @ kernel``: ``v`` (d, r), ``w``
+    (r, d)."""
+
+    @staticmethod
+    def init(key, dim: int, rank: int, n_layers: int = 3,
+             dtype=jnp.float32):
+        keys = jax.random.split(key, 2 * n_layers)
+        return {
+            "v": [initializers.glorot_uniform(keys[2 * i], (dim, rank), dtype)
+                  for i in range(n_layers)],
+            "w": [initializers.glorot_uniform(keys[2 * i + 1], (rank, dim),
+                                              dtype)
+                  for i in range(n_layers)],
+            "b": [jnp.zeros((dim,), dtype) for _ in range(n_layers)],
+        }
+
+    @staticmethod
+    def apply(params, x0: jnp.ndarray) -> jnp.ndarray:
+        x = x0
+        for v, w, b in zip(params["v"], params["w"], params["b"]):
+            x = x0 * ((x @ v) @ w + b) + x
         return x

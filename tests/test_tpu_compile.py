@@ -169,3 +169,65 @@ def test_trainer_updates_the_criteo_table_in_one_fusion(one_chip):
                if " fusion(" in line and 'op_name="jit(train_step)/update/'
                in line and table in line.split(" fusion(")[0]]
     assert outputs == [3], outputs
+
+
+def test_dlrm_dcnv2_mlperf_train_step_fits_one_chip(one_chip):
+    """The MPE search step of the benchmark's ``dlrm-dcnv2-mlperf``
+    configuration (one chip's share of MLPerf's DLRM-DCNv2: 4.29M rows of
+    d=128, 214 ids a sample in 26 multi-hot fields, 13 dense features, low
+    rank cross layers and the 1024-1024-512-256 MLP) at batch 2048 through
+    ``Trainer``, on shapes alone: it fits the chip's 16 GB."""
+    import json
+
+    from repro.core.mpe import MPEConfig
+    from repro.embeddings.table import FieldSpec
+    from repro.models.dlrm import DLRM, DLRMConfig
+    from repro.train.loop import Trainer
+    from repro.train.optimizer import adam
+
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                        "configs", "dlrm-dcnv2-mlperf.json")
+    with open(path) as f:
+        c = json.load(f)
+    batch, n_ids = 2048, sum(c["multi_hot_sizes"])
+    fields = tuple(FieldSpec(f"f{i}", v, k) for i, (v, k) in enumerate(
+        zip(c["field_vocabs"], c["multi_hot_sizes"])))
+    cfg = DLRMConfig(fields=fields, d_embed=c["d"],
+                     mlp_hidden=tuple(c["top_mlp"]), backbone="dcnv2",
+                     n_cross_layers=c["cross_layers"],
+                     cross_rank=c["cross_rank"], dense_in=c["dense_in"],
+                     bottom_hidden=tuple(c["bottom_mlp"]),
+                     compressor="mpe_search",
+                     comp_cfg=MPEConfig(bits=tuple(c["bits"]))._asdict(),
+                     use_batchnorm=False)
+    params, buffers, state = jax.eval_shape(
+        lambda k: DLRM.init(k, cfg), jax.random.PRNGKey(0))
+
+    def loss_fn(p, bu, st, b, *, step=None):
+        return DLRM.loss_fn(p, bu, st, b, cfg, lam=3e-5, train=True,
+                            step=step)
+
+    def tiny(s):
+        return jnp.zeros((1,) * len(s.shape), s.dtype)
+
+    tr = Trainer(loss_fn, jax.tree.map(tiny, params),
+                 jax.tree.map(tiny, buffers), state, adam(1e-3))
+    carry = jax.eval_shape(lambda p: {"params": p, "state": state,
+                                      "opt": adam(1e-3).init(p), "ef": None},
+                           params)
+
+    def place(tree):
+        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    compiled = tr._train_step.lower(
+        place(carry), place(buffers),
+        {"ids": _sds(one_chip, (batch, n_ids), jnp.int32),
+         "dense": _sds(one_chip, (batch, c["dense_in"]), jnp.float32),
+         "label": _sds(one_chip, (batch,), jnp.int32)},
+        _sds(one_chip, (), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    table_bytes = sum(c["field_vocabs"]) * c["d"] * 4
+    assert m.argument_size_in_bytes >= 3 * table_bytes    # θ, μ, ν
+    assert peak < V5E_HBM_BYTES, f"{peak / 1e9:.2f} GB"
