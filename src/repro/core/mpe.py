@@ -16,6 +16,10 @@ import numpy as np
 from repro.core import quantizer
 from repro.nn import init as initializers
 
+#: a TPU vector register's lane count: rows this wide or a multiple of it
+#: fill the fused Eq. 9 kernel's lanes
+LANES = 128
+
 
 class MPEConfig(NamedTuple):
     bits: tuple = (0, 1, 2, 3, 4, 5, 6)  # paper §5.1.5
@@ -75,7 +79,12 @@ class MPESearchEmbedding:
         Named scopes tag the device ops: ``embed_gather`` (the row, group and
         probability gathers; under ``transpose(...)`` the table gradient's
         zero-fill and scatter-add) and ``embed_quantize`` (Eq. 9 and its
-        straight-through backward)."""
+        straight-through backward).
+
+        Eq. 9 runs as the fused ``kernels.mpe_qat`` pair (one pass each
+        way, row-parallel over an active mesh) when the rows are lane-dense
+        (d a multiple of 128); narrower rows, which would fill a fraction of
+        the kernel's lanes, keep ``quantizer.mixed_expectation``."""
         from repro.dist.sharding import shard_batch_dim
         with jax.named_scope("embed_gather"):
             rows = jnp.take(params["emb"], ids, axis=0)
@@ -88,8 +97,15 @@ class MPESearchEmbedding:
             probs = jnp.take(p, gid, axis=0)                      # (*ids, m)
             probs = shard_batch_dim(probs)
         with jax.named_scope("embed_quantize"):
-            return quantizer.mixed_expectation(rows, probs, params["alpha"],
-                                               params["beta"], cfg.bits)
+            d = rows.shape[-1]
+            if d % LANES:
+                return quantizer.mixed_expectation(
+                    rows, probs, params["alpha"], params["beta"], cfg.bits)
+            from repro.dist.shard import sharded_mixed_expectation
+            out = sharded_mixed_expectation(
+                rows.reshape(-1, d), probs.reshape(-1, probs.shape[-1]),
+                params["alpha"], params["beta"], tuple(cfg.bits))
+            return out.reshape(rows.shape)
 
     @staticmethod
     def reg_loss(params, buffers, cfg: MPEConfig) -> jnp.ndarray:

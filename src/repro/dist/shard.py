@@ -56,7 +56,9 @@ Placement per wrapper:
                                own shard_maps with the (o, lse) residuals
                                stored sharded.
   ``sharded_mixed_expectation`` rows over every mesh axis (row-parallel
-                               QAT); no collectives, bit-exact.
+                               QAT); forward collective-free and bit-exact,
+                               backward one psum of the replicated α's and
+                               β's cotangents.
   ``sharded_value_and_grad``   the train step's grad: batch data-parallel
                                over the mesh, embedding-table leaves stored
                                row-sharded over ``rows_axes`` (specs from
@@ -726,9 +728,12 @@ def sharded_flash_attention(q, k, v, *, n_kv_heads: int | None = None,
 def sharded_mixed_expectation(rows, probs, alpha, beta, bits, *, mesh=None):
     """Eq. (9) expectation-over-widths under ``shard_map``: rows split over
     *every* mesh axis (the op is row-parallel — the natural placement for
-    the gathered rows of a batch-sharded train step); α/β replicated. No
-    collectives, bit-exact. Rows pad up to the device count and unpad after
-    (the pad-to-shard path)."""
+    the gathered rows of a batch-sharded train step); α/β replicated. The
+    forward has no collectives and is bit-exact; in the backward each shard
+    reduces ∂α and ∂β over its own rows and the ``shard_map`` transpose
+    psums them over the mesh (``check_vma=False`` inserts that psum for
+    every replicated input). Rows pad up to the device count and unpad
+    after (the pad-to-shard path)."""
     from repro.kernels.mpe_qat.ops import mixed_expectation_kernel
 
     mesh = active_mesh(mesh)

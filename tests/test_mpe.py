@@ -126,3 +126,57 @@ def test_lookup_differentiable(seed, lam):
     g = jax.grad(loss)(params)
     for leaf in jax.tree.leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
+
+
+@pytest.mark.parametrize("d", [16, 128])
+def test_lookup_eq9_path_follows_the_row_width(d, rng):
+    """Lane-dense rows (d % 128 == 0) run Eq. 9 through the fused
+    ``kernels.mpe_qat`` pair and match ``quantizer.mixed_expectation``, the
+    output and the gradients of every parameter, to float32 rounding (only
+    the summation order of ∂α, ∂β and ∂p differs), over a row count that
+    is not a multiple of the kernel's tile. Narrower rows lower with no
+    kernel, bit-identical to it."""
+    from repro.core import quantizer
+    cfg = MPEConfig()
+    n = 500
+    params, bufs = MPESearchEmbedding.init(jax.random.PRNGKey(2), n, d,
+                                           rng.zipf(1.3, n), cfg)
+    params = dict(params, gamma=jnp.asarray(
+        rng.normal(0, 0.01, params["gamma"].shape), jnp.float32),
+        beta=jnp.asarray(rng.normal(0, 1e-3, (d,)), jnp.float32))
+    ids = jnp.asarray(rng.integers(0, n, (5, 430)), jnp.int32)  # 2150 rows
+    w = jnp.asarray(rng.normal(0, 1, (5, 430, d)), jnp.float32)
+
+    def plain(p):
+        rows = jnp.take(p["emb"], ids, axis=0)
+        probs = jnp.take(MPESearchEmbedding.probabilities(p, cfg),
+                         jnp.take(bufs["group_of_feature"], ids), axis=0)
+        return quantizer.mixed_expectation(rows, probs, p["alpha"],
+                                           p["beta"], cfg.bits)
+
+    def lookup(p):
+        return MPESearchEmbedding.lookup(p, bufs, ids, cfg)
+
+    def loss(fn):
+        return lambda p: jnp.sum(jnp.sin(fn(p)) * w)
+
+    kernel = "pallas_call" in str(jax.make_jaxpr(jax.grad(loss(lookup)))(
+        params))
+    assert kernel == (d % 128 == 0)
+    got, want = jax.jit(lookup)(params), jax.jit(plain)(params)
+    g_got = jax.jit(jax.grad(loss(lookup)))(params)
+    g_want = jax.jit(jax.grad(loss(plain)))(params)
+    if not kernel:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for k in g_want:
+            np.testing.assert_array_equal(np.asarray(g_got[k]),
+                                          np.asarray(g_want[k]))
+        return
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-9)
+    for k in ("emb", "gamma", "alpha", "beta"):
+        scale = float(jnp.max(jnp.abs(g_want[k])))
+        assert scale > 0, k
+        np.testing.assert_allclose(np.asarray(g_got[k]),
+                                   np.asarray(g_want[k]), rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=k)

@@ -140,6 +140,38 @@ def test_mixed_expectation_parity(mesh_shape, rng):
 
 @pytest.mark.parametrize("mesh_shape", [(1, 4), (2, 2)])
 @multidevice
+def test_mixed_expectation_grad_parity(mesh_shape, rng):
+    """The row-parallel kernel's backward under a mesh: each shard's ∂α and
+    ∂β are partial sums, and the shard_map transpose sums them over the
+    shards, so they equal the one-device path's up to that reassociation;
+    ∂rows and ∂probs are row-local and bit-identical."""
+    from repro.kernels.mpe_qat.ops import (mixed_expectation_kernel,
+                                           mixed_expectation_kernel_sharded)
+    m, d = len(BITS), 128
+    rows = jnp.asarray(rng.normal(0, 3e-3, (301, d)), jnp.float32)
+    probs = jax.nn.softmax(jnp.asarray(rng.normal(0, 1, (301, m)),
+                                       jnp.float32), -1)
+    alpha = jnp.asarray([quantizer.init_alpha(3e-3, b) for b in BITS])
+    beta = jnp.asarray(rng.normal(0, 1e-4, (d,)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 1, (301, d)), jnp.float32)
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda r, p, a, b: jnp.sum(jnp.sin(fn(r, p, a, b, BITS)) * w),
+            argnums=(0, 1, 2, 3)))(rows, probs, alpha, beta)
+
+    ref = grads(mixed_expectation_kernel)
+    with use_mesh(_mesh(mesh_shape)):
+        got = grads(mixed_expectation_kernel_sharded)
+    for a, r in zip(got[:2], ref[:2]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+    for a, r in zip(got[2:], ref[2:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
+                                   atol=1e-6 * float(jnp.max(jnp.abs(r))))
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 4), (2, 2)])
+@multidevice
 def test_tiered_hot_lookup_parity(mesh_shape, rng):
     from repro.cache import TieredTableStore
     from repro.cache.tiers import tiered_hot_lookup

@@ -64,24 +64,32 @@ def test_mpe_lookup_compiles(one_chip, b):
     _assert_kernel(compiled)
 
 
-def _qat_args(one_chip, rows=2048 * 39):
-    return (_sds(one_chip, (rows, D), jnp.float32),
+# the criteo cell's rows (batch 2048 × 39 fields, d=16) and the dcnv2 cell's
+# (batch 2048 × 214 multi-hot slots, d=128, the width the search step runs
+# through the kernels)
+QAT_SHAPES = [(2048 * 39, D), (2048 * 214, 128)]
+
+
+def _qat_args(one_chip, rows, d):
+    return (_sds(one_chip, (rows, d), jnp.float32),
             _sds(one_chip, (rows, len(BITS)), jnp.float32),
             _sds(one_chip, (len(BITS),), jnp.float32),
-            _sds(one_chip, (D,), jnp.float32))
+            _sds(one_chip, (d,), jnp.float32))
 
 
-def test_mpe_qat_fwd_compiles(one_chip):
+@pytest.mark.parametrize("rows,d", QAT_SHAPES)
+def test_mpe_qat_fwd_compiles(one_chip, rows, d):
     from repro.kernels.mpe_qat.kernel import mixed_expectation_fwd
     compiled = jax.jit(lambda r, p, a, be: mixed_expectation_fwd(
         r, p, a, be, bits=BITS, interpret=False)).lower(
-        *_qat_args(one_chip)).compile()
+        *_qat_args(one_chip, rows, d)).compile()
     _assert_kernel(compiled)
 
 
-def test_mpe_qat_bwd_compiles(one_chip):
+@pytest.mark.parametrize("rows,d", QAT_SHAPES)
+def test_mpe_qat_bwd_compiles(one_chip, rows, d):
     from repro.kernels.mpe_qat.kernel import mixed_expectation_bwd
-    args = _qat_args(one_chip)
+    args = _qat_args(one_chip, rows, d)
     compiled = jax.jit(lambda r, p, a, be, g: mixed_expectation_bwd(
         r, p, a, be, g, bits=BITS, interpret=False)).lower(
         *args, args[0]).compile()
@@ -171,16 +179,20 @@ def test_trainer_updates_the_criteo_table_in_one_fusion(one_chip):
     assert outputs == [3], outputs
 
 
-def test_dlrm_dcnv2_mlperf_train_step_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def dcnv2_step(one_chip):
     """The MPE search step of the benchmark's ``dlrm-dcnv2-mlperf``
     configuration (one chip's share of MLPerf's DLRM-DCNv2: 4.29M rows of
     d=128, 214 ids a sample in 26 multi-hot fields, 13 dense features, low
     rank cross layers and the 1024-1024-512-256 MLP) at batch 2048 through
-    ``Trainer``, on shapes alone: it fits the chip's 16 GB."""
+    ``Trainer``, compiled on shapes alone, with its Pallas kernels built
+    for the chip (the backend here is the CPU, which would interpret
+    them). Returns the executable and the configuration."""
     import json
 
     from repro.core.mpe import MPEConfig
     from repro.embeddings.table import FieldSpec
+    from repro.kernels.mpe_qat import kernel as qat_kernel
     from repro.models.dlrm import DLRM, DLRMConfig
     from repro.train.loop import Trainer
     from repro.train.optimizer import adam
@@ -219,15 +231,39 @@ def test_dlrm_dcnv2_mlperf_train_step_fits_one_chip(one_chip):
     def place(tree):
         return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
 
-    compiled = tr._train_step.lower(
-        place(carry), place(buffers),
-        {"ids": _sds(one_chip, (batch, n_ids), jnp.int32),
-         "dense": _sds(one_chip, (batch, c["dense_in"]), jnp.float32),
-         "label": _sds(one_chip, (batch,), jnp.int32)},
-        _sds(one_chip, (), jnp.int32)).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(qat_kernel, "resolve_interpret", lambda interpret: False)
+        compiled = tr._train_step.lower(
+            place(carry), place(buffers),
+            {"ids": _sds(one_chip, (batch, n_ids), jnp.int32),
+             "dense": _sds(one_chip, (batch, c["dense_in"]), jnp.float32),
+             "label": _sds(one_chip, (batch,), jnp.int32)},
+            _sds(one_chip, (), jnp.int32)).compile()
+    return compiled, c
+
+
+def test_dlrm_dcnv2_mlperf_train_step_fits_one_chip(dcnv2_step):
+    """The step fits the chip's 16 GB."""
+    compiled, c = dcnv2_step
     m = compiled.memory_analysis()
     peak = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     table_bytes = sum(c["field_vocabs"]) * c["d"] * 4
     assert m.argument_size_in_bytes >= 3 * table_bytes    # θ, μ, ν
     assert peak < V5E_HBM_BYTES, f"{peak / 1e9:.2f} GB"
+
+
+def test_dlrm_dcnv2_mlperf_train_step_runs_eq9_fused(dcnv2_step):
+    """At d=128 the step runs Eq. 9 as the two named kernels, once each,
+    both under ``embed_quantize``, so that a trace reads them by name and
+    as the ``quantize`` part of the step."""
+    compiled, _ = dcnv2_step
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    for name in ("mpe_qat_fwd", "mpe_qat_bwd"):
+        calls = [line for line in entry.splitlines()
+                 if f"%{name}" in line.split(" = ", 1)[0]
+                 and "tpu_custom_call" in line]
+        assert len(calls) == 1, (name, calls)
+        op_name = calls[0].split('op_name="', 1)[1].split('"', 1)[0]
+        assert "embed_quantize" in op_name, op_name
