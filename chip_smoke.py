@@ -335,7 +335,7 @@ def sharded_serve_phase(cfg, *, n_model: int = 4, p99_rows: int = 512,
                         n_requests: int = 4, bulk_request_rows: int = 8192,
                         seed: int = 0, log=print) -> dict:
     """Serve one seeded packed table on one device and on a
-    ``host_mesh(1, n_model)`` engine with ``shard_lookup=True`` — psum, then
+    ``host_mesh(1, n_model)`` engine, whose lookups run sharded — psum, then
     a2a at a bucket capacity that forces spill. The sharded lookup of the
     first ``serve_p99`` chunk must match the one-device lookup within 1 ulp
     and the served logits within ``SHARD_LOGIT_RTOL``; the counts of values
@@ -387,7 +387,7 @@ def sharded_serve_phase(cfg, *, n_model: int = 4, p99_rows: int = 512,
     for comms, cap in (("psum", None), ("a2a", capacity)):
         mesh = host_mesh(n_data=1, n_model=n_model)
         t0 = time.perf_counter()
-        engine, got = serve(mesh=mesh, shard_lookup=True, lookup_comms=comms,
+        engine, got = serve(mesh=mesh, lookup_comms=comms,
                             bucket_capacity=cap)
         placed = jax.device_put(table, tree_named_shardings(
             mesh, packed_table_pspecs(table)))

@@ -11,9 +11,9 @@ shard_map wrapper in the repo.
 
 Mesh policy mirrors the test suite: with ≥4 devices (the CI staticcheck
 job sets ``--xla_force_host_platform_device_count=4`` before importing
-jax) the corpus compiles on a 2×2 ``("data", "model")`` mesh with
-``shard_lookup`` on, so the SC204 and BC5xx passes see the real
-``shard_map`` lowerings; on a stock single-device CPU it degrades to the
+jax) the corpus compiles on a 2×2 ``("data", "model")`` mesh, where the
+lookups run as ``shard_map``s, so the SC204 and BC5xx passes see the real
+lowerings; on a stock single-device CPU it degrades to the
 1×1 host mesh (sharding no-ops, still full precision/recompile
 coverage).
 
@@ -72,11 +72,11 @@ def build_corpus(mesh=None, *, seed: int = 4):
         engine.register_packed_model(
             "dlrm", DLRM, cfg, params, state, buffers,
             shapes={"serve_p99_a2a": 64}, lookup_split=False,
-            shard_lookup=True, lookup_comms="a2a", bucket_capacity=16)
+            lookup_comms="a2a", bucket_capacity=16)
         engine.register_tiered_model(
             "dlrm", DLRM, cfg, params, state, buffers, store,
-            shapes={"tiered_p99_a2a": 64}, shard_lookup=True,
-            lookup_comms="a2a", bucket_capacity=16)
+            shapes={"tiered_p99_a2a": 64}, lookup_comms="a2a",
+            bucket_capacity=16)
 
     lm_cfg = LMConfig(n_layers=2, d_model=32, n_heads=2, n_kv_heads=1,
                       head_dim=16, d_ff=64, vocab=50, remat=False)

@@ -30,10 +30,10 @@ from repro.cache.policy import (DecayAdmissionPolicy, StaticTierPolicy,
                                 TierPlan)
 from repro.cache.prefetch import PrefetchPipeline
 from repro.cache.tiers import (ColdPrefetch, TieredTableStore,
-                               tiered_hot_lookup, tiered_hot_lookup_fn)
+                               tiered_hot_lookup)
 
 __all__ = [
     "TieredTableStore", "ColdPrefetch", "tiered_hot_lookup",
-    "tiered_hot_lookup_fn", "PrefetchPipeline", "DecayAdmissionPolicy",
+    "PrefetchPipeline", "DecayAdmissionPolicy",
     "StaticTierPolicy", "TierPlan",
 ]

@@ -20,9 +20,11 @@ production scale.
     table's compression ratio.
 
 Lookups are bit-exact against ``core.inference.packed_lookup`` on the
-monolithic table at every hot fraction: both tiers gather the same packed
-words, unpack with the same static shifts and dequantize with the same
-``α_b · code + β`` expression, and the tier merge is a ``jnp.where`` on the
+monolithic table at every hot fraction. The hot tier *is* that lookup —
+``tiered_hot_lookup`` runs ``core.inference``'s routine over the hot
+subtables with the hot bit as its keep mask — and the cold fill unpacks the
+same packed words with the same static shifts and dequantizes with the same
+``α_b · code + β`` expression. The tier merge is a ``jnp.where`` on the
 tier mask (never an add), so no float combine can perturb a row.
 
 Per-tier hit/miss/byte counters are first-class — ``counters()`` backs the
@@ -51,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing
-from repro.core.inference import _pad_rows, _auto_pad_multiple
+from repro.core.inference import _auto_pad_multiple, _lookup_rows, _pad_rows
 from repro.core.quantizer import dequantize_codes, int_bounds, quantize_codes
 from repro.embeddings.frequency import hot_feature_mask
 
@@ -577,33 +579,15 @@ def _scatter_codes(b: int, d: int, codes_grid, wgrid, pos, words, width_i):
 
 def tiered_hot_lookup(hot, bits, d: int, ids: jnp.ndarray) -> jnp.ndarray:
     """Device-local gather from a hot tier: ids (any int shape) ->
-    (*ids.shape, d) fp32, **zeros at cold positions**.
-
-    Mirrors ``core.inference.packed_lookup`` bucket by bucket (same unpack
-    shifts, same dequant expression) but reads the hot subtables and masks on
-    the tier bit as well as the width bucket. Pure jnp + static shapes: safe
-    to close over in a jitted serve cell, shards under
+    (*ids.shape, d) fp32, **zeros at cold positions** — the packed lookup
+    of ``core.inference`` over the hot subtables, with ``tier_local`` as the
+    row index and the hot bit as its keep mask. Pure jnp + static shapes:
+    safe to close over in a jitted serve cell, shards under
     ``tiered_hot_pspecs``.
     """
     flat = ids.reshape(-1)
-    widx = jnp.take(hot["width_idx"], flat, axis=0)
-    lidx = jnp.take(hot["tier_local"], flat, axis=0)
-    is_hot = jnp.take(hot["is_hot"], flat, axis=0)
-    out = jnp.zeros((flat.shape[0], d), jnp.float32)
-    for i, b in enumerate(bits):
-        if b == 0:
-            continue
-        sub = hot["subtables"][f"b{b}"]
-        words = jnp.take(sub, jnp.clip(lidx, 0, sub.shape[0] - 1), axis=0)
-        codes = packing.unpack_codes(words, b, d)
-        deq = dequantize_codes(codes, hot["alpha"][i], hot["beta"])
-        out = jnp.where((is_hot & (widx == i))[:, None], deq, out)
+    out = _lookup_rows(hot["subtables"], hot["alpha"], hot["beta"], bits, d,
+                       jnp.take(hot["width_idx"], flat, axis=0),
+                       jnp.take(hot["tier_local"], flat, axis=0),
+                       keep=jnp.take(hot["is_hot"], flat, axis=0))
     return out.reshape(*ids.shape, d)
-
-
-def tiered_hot_lookup_fn(bits, d: int):
-    """``tiered_hot_lookup`` with the static metadata bound:
-    ``(hot_tree, ids) -> embeddings``. Jit-stable the same way
-    ``core.inference.packed_lookup_fn`` is."""
-    bits = tuple(bits)
-    return lambda hot, ids: tiered_hot_lookup(hot, bits, d, ids)

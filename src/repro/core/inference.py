@@ -6,10 +6,14 @@ feature id to (width bucket, local row). Sub-8-bit codes are packed into
 uint32 words (see ``repro.core.packing``); a lookup gathers the packed words,
 unpacks with static shifts, and dequantizes ``α_b · code + β``.
 
-The pure-jnp lookup below computes all width buckets and selects — static
-shapes, shards cleanly under pjit (subtables row-sharded over the model axis).
-``repro.kernels.mpe_lookup`` is the fused Pallas version of the per-bucket
-gather+unpack+dequant.
+This module owns the format, and ``_lookup_rows`` is the one place that
+decodes it: every width bucket is gathered, unpacked and dequantized for
+every id (static shapes), and a ``where`` keeps the id's own bucket. Its
+inputs are what the lookups differ by — the tier mask (``keep``, the hot
+bit of ``repro.cache.tiers``), the row shard a device owns (``shard``, for
+the ``shard_map`` bodies of ``repro.dist.shard``) and ``use_kernel`` (the
+fused Pallas gather+unpack+dequant of ``repro.kernels.mpe_lookup`` per
+bucket). ``packed_lookup`` is the routine on the whole table.
 """
 from __future__ import annotations
 
@@ -121,23 +125,53 @@ def build_packed_table(emb, bits_idx_per_feature, alpha, beta, cfg: MPEConfig,
     return table, meta
 
 
-def packed_lookup(table, meta, ids: jnp.ndarray) -> jnp.ndarray:
-    """ids: any int shape -> (*ids.shape, d) fp32 dequantized embeddings."""
-    bits = meta["bits"]
-    d = meta["d"]
-    flat = ids.reshape(-1)
-    widx = jnp.take(table["width_idx"], flat, axis=0)           # (B,)
-    lidx = jnp.take(table["local_idx"], flat, axis=0)           # (B,)
-    out = jnp.zeros((flat.shape[0], d), jnp.float32)
+def _bucket_dequant(sub, loc, alpha_i, beta, *, b: int, d: int,
+                    use_kernel: bool = False):
+    """Gather → unpack → dequantize rows ``loc`` of one width-``b``
+    subtable: (n,) -> (n, d) fp32, through ``dequantize_codes`` or, with
+    ``use_kernel``, the fused Pallas kernel."""
+    if use_kernel:
+        from repro.kernels.mpe_lookup.kernel import packed_lookup_pallas
+        return packed_lookup_pallas(loc, sub, alpha_i, beta, b=b, d=d)
+    words = jnp.take(sub, loc, axis=0)
+    return dequantize_codes(packing.unpack_codes(words, b, d), alpha_i, beta)
+
+
+def _lookup_rows(subtables, alpha, beta, bits, d: int, widx, lidx, *,
+                 keep=None, shard=0, use_kernel: bool = False):
+    """The packed lookup of ids already routed to ``(widx, lidx)`` (width
+    bucket, row in its subtable): (n,) -> (n, d) fp32.
+
+    ``subtables`` holds rows ``[shard·rows_b, (shard+1)·rows_b)`` of each
+    width's subtable, ``rows_b`` being its local row count. An id reads
+    zeros where ``keep`` is false, where its width is 0, or where this
+    shard does not own its row — so the row shards of one table sum to the
+    whole lookup with one non-zero term per id. With ``shard=0`` and the
+    whole subtables every row is owned."""
+    out = jnp.zeros((widx.shape[0], d), jnp.float32)
     for i, b in enumerate(bits):
         if b == 0:
             continue  # zero-width features contribute the zero vector
-        sub = table["subtables"][f"b{b}"]
-        words = jnp.take(sub, jnp.clip(lidx, 0, sub.shape[0] - 1), axis=0)
-        codes = packing.unpack_codes(words, b, d)               # (B, d)
-        deq = dequantize_codes(codes, table["alpha"][i], table["beta"])
-        out = jnp.where((widx == i)[:, None], deq, out)
-    return out.reshape(*ids.shape, d)
+        sub = subtables[f"b{b}"]
+        rows = sub.shape[0]
+        loc = lidx - shard * rows
+        sel = (widx == i) & (loc >= 0) & (loc < rows)
+        if keep is not None:
+            sel = sel & keep
+        deq = _bucket_dequant(sub, jnp.clip(loc, 0, rows - 1), alpha[i], beta,
+                              b=b, d=d, use_kernel=use_kernel)
+        out = jnp.where(sel[:, None], deq, out)
+    return out
+
+
+def packed_lookup(table, meta, ids: jnp.ndarray) -> jnp.ndarray:
+    """ids: any int shape -> (*ids.shape, d) fp32 dequantized embeddings."""
+    flat = ids.reshape(-1)
+    out = _lookup_rows(table["subtables"], table["alpha"], table["beta"],
+                       meta["bits"], meta["d"],
+                       jnp.take(table["width_idx"], flat, axis=0),
+                       jnp.take(table["local_idx"], flat, axis=0))
+    return out.reshape(*ids.shape, meta["d"])
 
 
 def packed_lookup_fn(meta):

@@ -12,33 +12,35 @@ real mesh.
 
 Placement per wrapper:
 
-  ``sharded_packed_lookup``    subtables row-sharded over ``rows_axes``
-                               ("model"), ids batch-sharded over the data
-                               axes. Two comms paths, selected by
-                               ``lookup_comms``: ``"psum"`` (default) does a
-                               device-local gather+unpack+dequant with an
-                               ownership mask, then ONE ``psum`` over the
-                               row axes merges the buckets — each id owns
-                               exactly one (bucket, row), so the psum adds
-                               one non-zero term to zeros, bit-exact against
-                               the jitted single-device reference. ``"a2a"``
-                               ships only the *packed uint32 words*: a
-                               capacity-bucketed ``all_to_all`` id shuffle
-                               (``plan_buckets``) routes each id to its
-                               owner shard, the owner gathers the packed
-                               row, a second ``all_to_all`` returns the
-                               words and the *requesting* shard dequantizes
-                               — ~32/b× fewer bytes than psum-ing the
-                               dequantized (batch, d) f32 activation when
-                               the row axes are wide. Ids that overflow a
-                               bucket deterministically spill to a masked
-                               integer psum of the same packed words, so
-                               the a2a path is bit-exact at ANY capacity
-                               (nothing is dropped; see ``plan_buckets``).
-  ``sharded_tiered_hot_lookup``  same layout (and the same two comms paths)
-                               for the hot tier of a
-                               ``repro.cache.TieredTableStore`` (zeros at
-                               cold positions, merged by the caller).
+  ``sharded_packed_lookup``    one lookup, with an optional hot mask: the
+  ``sharded_tiered_hot_lookup``  packed table's subtables (or a
+                               ``cache.tiers.TieredTableStore`` hot tier's,
+                               keeping hot ids only — zeros at cold
+                               positions, merged by the caller) row-sharded
+                               over ``rows_axes`` ("model"), ids
+                               batch-sharded over the data axes. Each device
+                               decodes with ``core.inference``'s routine,
+                               told which row shard it owns. Two comms
+                               paths, selected by ``lookup_comms``:
+                               ``"psum"`` (default) merges the device-local
+                               results with ONE ``psum`` over the row axes —
+                               each id owns exactly one (bucket, row), so
+                               the psum adds one non-zero term to zeros,
+                               bit-exact against the jitted single-device
+                               reference. ``"a2a"`` ships only the *packed
+                               uint32 words*: a capacity-bucketed
+                               ``all_to_all`` id shuffle (``plan_buckets``)
+                               routes each id to its owner shard, the owner
+                               gathers the packed row, a second
+                               ``all_to_all`` returns the words and the
+                               *requesting* shard decodes them — ~32/b×
+                               fewer bytes than psum-ing the dequantized
+                               (batch, d) f32 activation when the row axes
+                               are wide. Ids that overflow a bucket
+                               deterministically spill to a masked integer
+                               psum of the same packed words, so the a2a
+                               path is bit-exact at ANY capacity (nothing is
+                               dropped; see ``plan_buckets``).
   ``sharded_embedding_bag``    table rows over ``rows_axes``, bags over the
                                data axes; per-device partial bag sums +
                                psum. Differentiable: a ``custom_vjp`` runs
@@ -86,7 +88,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import packing
-from repro.core.quantizer import dequantize_codes
+from repro.core.inference import _lookup_rows
 from repro.dist.mesh import current_mesh
 from repro.dist.sharding import replicate_like
 
@@ -225,14 +227,34 @@ def spill_capacity(slice_len: int, capacity: int, n_shards: int) -> int:
     return n_shards * max(0, slice_len - capacity)
 
 
-def _cap_slice(batch: int, n_shards: int, capacity) -> tuple[int, int]:
-    """(slice_len, clamped capacity): each of the ``n_shards`` batch slices
-    holds ``ceil(batch / n_shards)`` ids; a capacity of None (or anything
-    >= slice_len) makes the plan statically spill-free."""
+def _route_plan(flat, width_idx, local_idx, shard_rows, bits, *,
+                n_shards: int, capacity, keep=None):
+    """The a2a routing plan of one id batch, shared by the lookup body and
+    ``lookup_route_stats``: pad the batch to ``n_shards`` equal slices, find
+    each id's owner shard (``shard_rows[i]`` rows of width ``i`` per shard,
+    the ``pad_rows_to_shard``-ed count), route the ids in the batch with a
+    non-zero width (and ``keep``, where given) and bucket them under
+    ``capacity``, clamped to the slice (None: statically spill-free).
+    Returns ``(padded ids, widx, lidx, route, plan, clamped capacity)``."""
+    batch = flat.shape[0]
     slice_len = -(-batch // n_shards)
-    if capacity is None:
-        return slice_len, slice_len
-    return slice_len, max(1, min(int(capacity), slice_len))
+    cap = slice_len if capacity is None else \
+        max(1, min(int(capacity), slice_len))
+    bp = n_shards * slice_len
+    fl_p = jnp.pad(flat, (0, bp - batch))
+    widx = jnp.take(width_idx, fl_p, axis=0)
+    lidx = jnp.take(local_idx, fl_p, axis=0)
+    nz = jnp.asarray([b != 0 for b in bits])
+    route = (jnp.arange(bp) < batch) & jnp.take(nz, widx, axis=0)
+    if keep is not None:
+        route = route & jnp.take(keep, fl_p, axis=0)
+    owner = jnp.clip(
+        lidx // jnp.take(jnp.asarray(shard_rows, jnp.int32), widx, axis=0),
+        0, n_shards - 1)
+    plan = plan_buckets(owner.reshape(n_shards, slice_len),
+                        route.reshape(n_shards, slice_len),
+                        n_shards=n_shards, capacity=cap)
+    return fl_p, widx, lidx, route, plan, cap
 
 
 def _route_words(subs, widths, widx, lidx, shard, n_words, mask=None):
@@ -256,8 +278,8 @@ def _route_words(subs, widths, widx, lidx, shard, n_words, mask=None):
     return words, owned
 
 
-def _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl, *, mesh, rows_ax,
-                bits, d, capacity, use_kernel, ok_vec=None):
+def _a2a_lookup(subs, vecs, fl, *, mesh, rows_ax, bits, d, capacity,
+                use_kernel):
     """Body of the capacity-bucketed all-to-all lookup (inside shard_map).
 
     The ids are replicated along ``rows_ax`` (they enter sharded over the
@@ -270,35 +292,24 @@ def _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl, *, mesh, rows_ax,
          ``all_gather`` rebuilds the full (batch, words) array;
       4. overflowed ids merge through ONE masked integer psum of a static
          ``spill_capacity``-row buffer — exact (each row has one writer);
-      5. the requesting shard unpacks + dequantizes through the sanctioned
-         ``core.quantizer.dequantize_codes`` path (or the fused kernel).
+      5. the requesting shard decodes the words with ``core.inference``'s
+         lookup routine, the gathered words standing in for the subtables.
 
     Identical words → identical static-shift unpack → identical dequant, so
-    the result is bit-exact vs the psum path at ANY capacity. ``ok_vec`` is
-    an optional replicated per-id validity vector (the tiered hot bit):
-    unselected ids are not routed and output zeros, matching the psum
-    path's ownership mask."""
+    the result is bit-exact vs the psum path at ANY capacity. Ids outside
+    ``vecs["keep"]`` (the tiered hot bit, where given) are not routed and
+    output zeros, matching the psum path's keep mask."""
     mp = _axes_size(mesh, rows_ax)
-    batch = fl.shape[0]
-    slice_len, cap = _cap_slice(batch, mp, capacity)
-    bp = mp * slice_len
-    n_spill = spill_capacity(slice_len, cap, mp)
     widths = [(i, b) for i, b in enumerate(bits) if b != 0]
     n_words = max(packing.words_per_row(d, b) for _, b in widths)
-
-    fl_p = jnp.pad(fl, (0, bp - batch))
-    widx = jnp.take(width_idx, fl_p, axis=0)
-    lidx = jnp.take(local_idx, fl_p, axis=0)
-    nz = jnp.asarray([b != 0 for b in bits])
-    route = (jnp.arange(bp) < batch) & jnp.take(nz, widx, axis=0)
-    if ok_vec is not None:
-        route = route & jnp.take(ok_vec, fl_p, axis=0)
-    rows_loc_vec = jnp.asarray(
-        [subs[f"b{b}"].shape[0] if b else 1 for b in bits], jnp.int32)
-    owner = jnp.clip(lidx // jnp.take(rows_loc_vec, widx, axis=0), 0, mp - 1)
-    plan = plan_buckets(owner.reshape(mp, slice_len),
-                        route.reshape(mp, slice_len),
-                        n_shards=mp, capacity=cap)
+    local_idx, width_idx = vecs["local"], vecs["width"]
+    fl_p, widx, lidx, route, plan, cap = _route_plan(
+        fl, width_idx, local_idx,
+        [subs[f"b{b}"].shape[0] if b else 1 for b in bits], bits,
+        n_shards=mp, capacity=capacity, keep=vecs.get("keep"))
+    bp = fl_p.shape[0]
+    slice_len = bp // mp
+    n_spill = spill_capacity(slice_len, cap, mp)
 
     me = rows_shard_index(mesh, rows_ax)
     ids_me = jax.lax.dynamic_slice_in_dim(fl_p, me * slice_len, slice_len)
@@ -337,46 +348,28 @@ def _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl, *, mesh, rows_ax,
             sp[:, None],
             jnp.take(buf, jnp.clip(sp_rank, 0, n_spill - 1), axis=0), full)
 
-    # (5) dequant on the requesting shard (PF102-sanctioned path)
-    out = jnp.zeros((bp, d), jnp.float32)
-    for i, b in widths:
-        wb = packing.words_per_row(d, b)
-        deq = _bucket_dequant(full[:, :wb], jnp.arange(bp), alpha[i], beta,
-                              b=b, d=d, use_kernel=use_kernel)
-        out = jnp.where((route & (widx == i))[:, None], deq, out)
-    return out[:batch]
+    # (5) decode on the requesting shard: row j of ``full`` is id j's row
+    words_by_width = {f"b{b}": full[:, :packing.words_per_row(d, b)]
+                      for _, b in widths}
+    out = _lookup_rows(words_by_width, vecs["alpha"], vecs["beta"], bits, d,
+                       widx, jnp.arange(bp), keep=route,
+                       use_kernel=use_kernel)
+    return out[:fl.shape[0]]
 
 
 def lookup_route_stats(table, meta, ids, *, n_shards: int,
                        bucket_capacity: int | None = None) -> dict:
-    """Deterministic routing counters for the a2a path of one lookup.
-
-    Mirrors the in-body plan exactly — same batch padding, owner derivation
-    (over ``pad_rows_to_shard``-ed subtables) and capacity clamp — so the
-    numbers are reproducible bench-gate metrics, not samples."""
-    bits, d = meta["bits"], meta["d"]
+    """Deterministic routing counters for the a2a path of one lookup — the
+    lookup body's own plan (``_route_plan``), so the numbers are
+    reproducible bench-gate metrics, not samples."""
+    bits = meta["bits"]
     flat = jnp.asarray(ids).reshape(-1)
-    batch = flat.shape[0]
-    slice_len, cap = _cap_slice(batch, n_shards, bucket_capacity)
-    bp = n_shards * slice_len
-    rows_loc = []
-    for b in bits:
-        if b == 0:
-            rows_loc.append(1)
-            continue
-        rows = table["subtables"][f"b{b}"].shape[0]
-        rows_loc.append((rows + (-rows) % n_shards) // n_shards)
-    fl_p = jnp.pad(flat, (0, bp - batch))
-    widx = jnp.take(table["width_idx"], fl_p, axis=0)
-    lidx = jnp.take(table["local_idx"], fl_p, axis=0)
-    nz = jnp.asarray([b != 0 for b in bits])
-    route = (jnp.arange(bp) < batch) & jnp.take(nz, widx, axis=0)
-    owner = jnp.clip(
-        lidx // jnp.take(jnp.asarray(rows_loc, jnp.int32), widx, axis=0),
-        0, n_shards - 1)
-    plan = plan_buckets(owner.reshape(n_shards, slice_len),
-                        route.reshape(n_shards, slice_len),
-                        n_shards=n_shards, capacity=cap)
+    shard_rows = [-(-table["subtables"][f"b{b}"].shape[0] // n_shards)
+                  if b else 1 for b in bits]
+    fl_p, _, _, route, plan, cap = _route_plan(
+        flat, table["width_idx"], table["local_idx"], shard_rows, bits,
+        n_shards=n_shards, capacity=bucket_capacity)
+    slice_len = fl_p.shape[0] // n_shards
     n_slots = n_shards * n_shards * cap
     return {
         "slice_len": slice_len,
@@ -392,18 +385,71 @@ def lookup_route_stats(table, meta, ids, *, n_shards: int,
 
 
 # ---------------------------------------------------------------------------
-# packed-table lookup (repro.kernels.mpe_lookup / core.inference)
+# packed-table lookup (core.inference)
 # ---------------------------------------------------------------------------
 
-def _bucket_dequant(sub, loc, alpha_i, beta, *, b, d, use_kernel):
-    """Device-local gather+unpack+dequant of one width bucket — the fused
-    Pallas kernel or its jnp formulation, on local rows only."""
-    if use_kernel:
-        from repro.kernels.mpe_lookup.kernel import packed_lookup_pallas
-        return packed_lookup_pallas(loc, sub, alpha_i, beta, b=b, d=d)
-    words = jnp.take(sub, loc, axis=0)
-    codes = packing.unpack_codes(words, b, d)
-    return dequantize_codes(codes, alpha_i, beta)
+def _decode(subs, vecs, fl, *, bits, d, shard=0, use_kernel=False):
+    """``core.inference._lookup_rows`` of the flat ids ``fl`` through the
+    per-feature vectors ``vecs`` (see ``_packed_lookup``)."""
+    keep = vecs.get("keep")
+    return _lookup_rows(
+        subs, vecs["alpha"], vecs["beta"], bits, d,
+        jnp.take(vecs["width"], fl, axis=0),
+        jnp.take(vecs["local"], fl, axis=0),
+        keep=None if keep is None else jnp.take(keep, fl, axis=0),
+        shard=shard, use_kernel=use_kernel)
+
+
+def _packed_lookup(tree, bits, d: int, ids, *, local: str, keep=None,
+                   rows_axes, mesh, use_kernel, lookup_comms, bucket_capacity):
+    """The one sharded lookup behind both public wrappers, over a packed
+    table or a hot tier ``tree``: its row index is ``tree[local]`` and, for
+    a hot tier, ``tree[keep]`` is the keep mask (zeros elsewhere). The
+    replicated per-feature vectors travel as ``vecs``: ``width`` (bucket),
+    ``local``, ``alpha``, ``beta`` and ``keep``.
+
+    Off a multi-device mesh this is ``core.inference``'s routine on one
+    device. On one, the subtables row-shard over ``rows_axes`` and the ids
+    batch-shard over the other axes; the psum body runs the routine on the
+    device's own row shard and ONE psum adds the one non-zero term per id
+    to zeros — bit-exact. ``lookup_comms="a2a"`` runs ``_a2a_lookup``
+    instead, falling back to psum when the row axes hold a single shard."""
+    if lookup_comms not in LOOKUP_COMMS:
+        raise ValueError(f"lookup_comms must be one of {LOOKUP_COMMS}, "
+                         f"got {lookup_comms!r}")
+    subtables = tree["subtables"]
+    vecs = {"width": tree["width_idx"], "local": tree[local],
+            "alpha": tree["alpha"], "beta": tree["beta"]}
+    if keep is not None:
+        vecs["keep"] = tree[keep]
+    flat = ids.reshape(-1)
+    mesh = active_mesh(mesh)
+    if mesh is None:
+        out = _decode(subtables, vecs, flat, bits=bits, d=d,
+                      use_kernel=use_kernel)
+        return out.reshape(*ids.shape, d)
+    rows_ax = _present_axes(mesh, rows_axes)
+    mp = _axes_size(mesh, rows_ax)
+    use_a2a = lookup_comms == "a2a" and mp > 1 and any(bits)
+    batch_ax = _batch_entry(mesh, flat.shape[0], _dp_axes_of(mesh, rows_ax))
+
+    def body(subs, v, fl):
+        if use_a2a:
+            return _a2a_lookup(subs, v, fl, mesh=mesh, rows_ax=rows_ax,
+                               bits=bits, d=d, capacity=bucket_capacity,
+                               use_kernel=use_kernel)
+        out = _decode(subs, v, fl, bits=bits, d=d,
+                      shard=rows_shard_index(mesh, rows_ax),
+                      use_kernel=use_kernel)
+        return jax.lax.psum(out, rows_ax) if rows_ax else out
+
+    subs = {k: pad_rows_to_shard(v, mp) for k, v in subtables.items()}
+    in_specs = ({k: P(rows_ax or None, None) for k in subs}, P(),
+                P(batch_ax))
+    out = shard_map(body, mesh=mesh, in_specs=in_specs,
+                    out_specs=P(batch_ax, None), check_vma=False)(
+        subs, vecs, flat)
+    return out.reshape(*ids.shape, d)
 
 
 def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
@@ -416,127 +462,31 @@ def sharded_packed_lookup(table, meta, ids, *, rows_axes=("model",),
     ``"psum"`` (one float psum over the row axes) or ``"a2a"`` (the
     capacity-bucketed all-to-all of ``_a2a_lookup`` — ``bucket_capacity``
     ids per (slice, shard) bucket, overflow spilling to an integer psum).
-    Both are bit-exact vs the single-device reference; a2a falls back to
-    psum when the row axes resolve to a single shard.
+    Both are bit-exact vs the single-device reference.
 
     Degrades to the single-device lookup when no multi-device mesh is active
     (or none of ``rows_axes`` is on it). ``use_kernel`` runs the fused
-    Pallas kernel per bucket inside the body."""
-    from repro.core.inference import packed_lookup
-
-    if lookup_comms not in LOOKUP_COMMS:
-        raise ValueError(f"lookup_comms must be one of {LOOKUP_COMMS}, "
-                         f"got {lookup_comms!r}")
-    mesh = active_mesh(mesh)
-    if mesh is None:
-        if use_kernel:
-            from repro.kernels.mpe_lookup.ops import packed_lookup_kernel
-            return packed_lookup_kernel(table, meta, ids)
-        return packed_lookup(table, meta, ids)
-    rows_ax = _present_axes(mesh, rows_axes)
-    mp = _axes_size(mesh, rows_ax)
-
-    bits, d = meta["bits"], meta["d"]
-    use_a2a = lookup_comms == "a2a" and mp > 1 and any(bits)
-    dp = _dp_axes_of(mesh, rows_ax)
-    flat = ids.reshape(-1)
-    batch_ax = _batch_entry(mesh, flat.shape[0], dp)
-
-    tbl = dict(table, subtables={k: pad_rows_to_shard(v, mp)
-                                 for k, v in table["subtables"].items()})
-
-    def body(subs, local_idx, width_idx, alpha, beta, fl):
-        if use_a2a:
-            return _a2a_lookup(subs, local_idx, width_idx, alpha, beta, fl,
-                               mesh=mesh, rows_ax=rows_ax, bits=bits, d=d,
-                               capacity=bucket_capacity,
-                               use_kernel=use_kernel)
-        widx = jnp.take(width_idx, fl, axis=0)
-        lidx = jnp.take(local_idx, fl, axis=0)
-        base = rows_shard_index(mesh, rows_ax)
-        out = jnp.zeros((fl.shape[0], d), jnp.float32)
-        for i, b in enumerate(bits):
-            if b == 0:
-                continue  # zero-width features contribute the zero vector
-            sub = subs[f"b{b}"]
-            rows_loc = sub.shape[0]
-            loc = lidx - base * rows_loc
-            own = (loc >= 0) & (loc < rows_loc)
-            deq = _bucket_dequant(sub, jnp.clip(loc, 0, rows_loc - 1),
-                                  alpha[i], beta, b=b, d=d,
-                                  use_kernel=use_kernel)
-            out = jnp.where((own & (widx == i))[:, None], deq, out)
-        # one non-zero owner per id: the psum adds zeros — exact
-        return jax.lax.psum(out, rows_ax) if rows_ax else out
-
-    in_specs = ({k: P(rows_ax or None, None) for k in tbl["subtables"]},
-                P(None), P(None), P(None), P(None), P(batch_ax))
-    out = shard_map(body, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(batch_ax, None), check_vma=False)(
-        tbl["subtables"], tbl["local_idx"], tbl["width_idx"],
-        tbl["alpha"], tbl["beta"], flat)
-    return out.reshape(*ids.shape, d)
+    Pallas kernel per bucket, on a mesh or off it."""
+    return _packed_lookup(table, meta["bits"], meta["d"], ids,
+                          local="local_idx", rows_axes=rows_axes, mesh=mesh,
+                          use_kernel=use_kernel, lookup_comms=lookup_comms,
+                          bucket_capacity=bucket_capacity)
 
 
 def sharded_tiered_hot_lookup(hot, bits, d: int, ids, *,
                               rows_axes=("model",), mesh=None,
                               lookup_comms: str = "psum",
                               bucket_capacity: int | None = None):
-    """``repro.cache.tiers.tiered_hot_lookup`` under ``shard_map``: hot
-    subtables row-sharded per ``tiered_hot_pspecs``, zeros at cold positions
-    (the caller merges the cold fill). Bit-exact like the packed lookup —
-    the ownership mask additionally requires the hot bit. ``lookup_comms``
-    / ``bucket_capacity`` select the same two merge paths as
-    ``sharded_packed_lookup`` (under a2a, only hot ids are routed)."""
-    from repro.cache.tiers import tiered_hot_lookup
-
-    if lookup_comms not in LOOKUP_COMMS:
-        raise ValueError(f"lookup_comms must be one of {LOOKUP_COMMS}, "
-                         f"got {lookup_comms!r}")
-    mesh = active_mesh(mesh)
-    if mesh is None:
-        return tiered_hot_lookup(hot, bits, d, ids)
-    rows_ax = _present_axes(mesh, rows_axes)
-    mp = _axes_size(mesh, rows_ax)
-    use_a2a = lookup_comms == "a2a" and mp > 1 and any(bits)
-
-    dp = _dp_axes_of(mesh, rows_ax)
-    flat = ids.reshape(-1)
-    batch_ax = _batch_entry(mesh, flat.shape[0], dp)
-    hot_p = dict(hot, subtables={k: pad_rows_to_shard(v, mp)
-                                 for k, v in hot["subtables"].items()})
-
-    def body(subs, tier_local, is_hot, width_idx, alpha, beta, fl):
-        if use_a2a:
-            return _a2a_lookup(subs, tier_local, width_idx, alpha, beta, fl,
-                               mesh=mesh, rows_ax=rows_ax, bits=bits, d=d,
-                               capacity=bucket_capacity, use_kernel=False,
-                               ok_vec=is_hot)
-        widx = jnp.take(width_idx, fl, axis=0)
-        lidx = jnp.take(tier_local, fl, axis=0)
-        hot_bit = jnp.take(is_hot, fl, axis=0)
-        base = rows_shard_index(mesh, rows_ax)
-        out = jnp.zeros((fl.shape[0], d), jnp.float32)
-        for i, b in enumerate(bits):
-            if b == 0:
-                continue
-            sub = subs[f"b{b}"]
-            rows_loc = sub.shape[0]
-            loc = lidx - base * rows_loc
-            own = (loc >= 0) & (loc < rows_loc) & hot_bit
-            words = jnp.take(sub, jnp.clip(loc, 0, rows_loc - 1), axis=0)
-            codes = packing.unpack_codes(words, b, d)
-            deq = dequantize_codes(codes, alpha[i], beta)
-            out = jnp.where((own & (widx == i))[:, None], deq, out)
-        return jax.lax.psum(out, rows_ax) if rows_ax else out
-
-    in_specs = ({k: P(rows_ax or None, None) for k in hot_p["subtables"]},
-                P(None), P(None), P(None), P(None), P(None), P(batch_ax))
-    out = shard_map(body, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(batch_ax, None), check_vma=False)(
-        hot_p["subtables"], hot_p["tier_local"], hot_p["is_hot"],
-        hot_p["width_idx"], hot_p["alpha"], hot_p["beta"], flat)
-    return out.reshape(*ids.shape, d)
+    """``cache.tiers.tiered_hot_lookup`` under ``shard_map``: the same
+    lookup as ``sharded_packed_lookup`` over the hot subtables (layout:
+    ``tiered_hot_pspecs``), rows found through ``tier_local`` and the hot
+    bit as the keep mask — zeros at cold positions, which the caller fills
+    (under a2a, only hot ids are routed)."""
+    return _packed_lookup(hot, tuple(bits), d, ids, local="tier_local",
+                          keep="is_hot", rows_axes=rows_axes, mesh=mesh,
+                          use_kernel=False,
+                          lookup_comms=lookup_comms,
+                          bucket_capacity=bucket_capacity)
 
 
 # ---------------------------------------------------------------------------

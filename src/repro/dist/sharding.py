@@ -326,7 +326,7 @@ def host_packed_table_pspecs(table_sds, *, rows_axes=("pod", "model")):
 
 @_family
 def tiered_hot_pspecs(hot_sds, *, rows_axes=("model",)):
-    """Pspecs for the **hot tier** of a ``repro.cache.TieredTableStore``.
+    """Pspecs for the **hot tier** of a ``cache.tiers.TieredTableStore``.
 
     The hot tier is the device-resident half of the hot/cold split and the
     only half that ever sees the mesh — the cold tier lives in host memory

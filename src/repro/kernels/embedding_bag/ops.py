@@ -31,18 +31,3 @@ def _bwd(res, g):
 
 embedding_bag_kernel.defvjp(_fwd, _bwd)
 
-
-def embedding_bag_kernel_sharded(table, ids, mask, *, rows_axes=("model",),
-                                 mesh=None):
-    """Differentiable bag under ``shard_map``: table rows over ``rows_axes``,
-    bags over the data axes, partial sums psum-merged; the backward pass is
-    a ``custom_vjp`` that segment-sums each device's owned cotangent rows
-    locally (no dense-gradient collective over the row axis). Tolerance
-    ~1e-6 vs the single-device kernel when the rows really split (the psum
-    reassociates the bag sum — pinned by
-    ``tests/test_shard_a2a.py::test_embedding_bag_psum_tolerance``); falls
-    back to the kernel when no multi-device mesh is active (see
-    ``repro.dist.shard``)."""
-    from repro.dist.shard import sharded_embedding_bag
-    return sharded_embedding_bag(table, ids, mask, rows_axes=rows_axes,
-                                 mesh=mesh)

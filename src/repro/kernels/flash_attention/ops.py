@@ -51,19 +51,3 @@ def flash_attention_kernel(q, k, v, *, n_kv_heads: int | None = None,
     of = _flash_flat(qf, kf, vf, causal, min(bq, s), min(bk, s))
     return jnp.moveaxis(of.reshape(b, hq, s, hd), 1, 2)
 
-
-def flash_attention_kernel_sharded(q, k, v, *, n_kv_heads: int | None = None,
-                                   causal: bool = True, bq: int = 128,
-                                   bk: int = 128, head_axes=("model",),
-                                   mesh=None):
-    """Flash attention under ``shard_map``: batch over the data axes, heads
-    over ``head_axes`` — collective-free and bit-exact vs the single-device
-    kernel, forward and backward (a ``custom_vjp`` reruns the kernel with
-    logsumexp stats saved and drives the Pallas backward kernel under the
-    same specs, so grads match the unsharded ``jax.value_and_grad`` exactly).
-    Falls back to ``flash_attention_kernel`` when no multi-device mesh is
-    active (see ``repro.dist.shard``)."""
-    from repro.dist.shard import sharded_flash_attention
-    return sharded_flash_attention(q, k, v, n_kv_heads=n_kv_heads,
-                                   causal=causal, bq=bq, bk=bk,
-                                   head_axes=head_axes, mesh=mesh)

@@ -34,14 +34,3 @@ def _bwd(bits, res, g):
 
 mixed_expectation_kernel.defvjp(_fwd, _bwd)
 
-
-def mixed_expectation_kernel_sharded(rows, probs, alpha, beta, bits, *,
-                                     mesh=None):
-    """Eq. (9) under ``shard_map``: rows split over every mesh axis
-    (row-parallel, bit-exact forward), padded up to the device count and
-    unpadded after; α's and β's cotangents are summed over the shards.
-    Falls back to the fused kernel when no multi-device mesh is active
-    (see ``repro.dist.shard``)."""
-    from repro.dist.shard import sharded_mixed_expectation
-    return sharded_mixed_expectation(rows, probs, alpha, beta, bits,
-                                     mesh=mesh)

@@ -38,7 +38,7 @@ from repro.models.lm import LM
 from repro.models.sasrec import SASRec
 from repro.models.two_tower import TwoTower
 from repro.models.wide_deep import WideDeep
-from repro.serve.cells import packed_score_step
+from repro.serve.cells import score_step
 from repro.train.optimizer import adam, apply_updates
 
 PACKED_HIST = (0.0, 0.30, 0.20, 0.20, 0.10, 0.10, 0.10)  # widths 0..6 (b>0 rows)
@@ -386,7 +386,7 @@ def _flat_ctr_cell(spec, shape, batch, train, dp, rows_axes, multi_pod, *,
     ids_sds = sds((n_eff, len(fields)), jnp.int32)
     ids_ps = P(rows_axes if shape == "retrieval_cand" else dp, None)
 
-    serve_step = packed_score_step(
+    serve_step = score_step(
         model, cfg, top_k=100 if shape == "retrieval_cand" else None)
 
     return _serve_cell(

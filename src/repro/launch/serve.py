@@ -97,8 +97,7 @@ def train_packed_dlrm(*, field_vocabs=DEFAULT_VOCABS, train_steps: int = 120,
 
 def build_engine(cfg, params, state, buffers, *, p99_rows: int = 512,
                  bulk_rows: int = 4096, lookup_split: bool = True,
-                 store=None, mesh=None, shard_lookup: bool | None = None,
-                 lookup_comms: str = "psum",
+                 store=None, mesh=None, lookup_comms: str = "psum",
                  bucket_capacity: int | None = None,
                  queue_capacity: int = 1024, quotas=None,
                  shed_watermark: float = 1.0,
@@ -108,10 +107,9 @@ def build_engine(cfg, params, state, buffers, *, p99_rows: int = 512,
     With a ``repro.cache.TieredTableStore`` in ``store``, the same shapes are
     additionally registered as tiered cells (``tiered_p99``/``tiered_bulk``)
     served through ``engine.score_tiered``. A multi-device ``mesh`` compiles
-    every cell against it; ``shard_lookup`` (default: on exactly when the
-    mesh has >1 device) routes the packed/hot gathers through the
-    ``shard_map`` wrappers of ``repro.dist.shard``; ``lookup_comms="a2a"``
-    switches those wrappers to the capacity-bucketed all-to-all id shuffle
+    every cell against it, the packed/hot gathers as the ``shard_map``
+    lookups of ``repro.dist.shard``; ``lookup_comms="a2a"``
+    switches those lookups to the capacity-bucketed all-to-all id shuffle
     (``bucket_capacity`` bounds ids per destination shard, overflow spills
     to the psum merge — bit-exact at any capacity). ``quotas`` /
     ``shed_watermark`` / ``coalesce_window_ms`` / ``clock`` pass through to
@@ -120,18 +118,15 @@ def build_engine(cfg, params, state, buffers, *, p99_rows: int = 512,
     engine = Engine(mesh=mesh, queue_capacity=queue_capacity, quotas=quotas,
                     shed_watermark=shed_watermark,
                     coalesce_window_ms=coalesce_window_ms, clock=clock)
-    if shard_lookup is None:
-        shard_lookup = engine.mesh.size > 1
     engine.register_packed_model(
         "dlrm", DLRM, cfg, params, state, buffers,
         shapes={"serve_p99": p99_rows, "serve_bulk": bulk_rows},
-        lookup_split=lookup_split, shard_lookup=shard_lookup,
-        lookup_comms=lookup_comms, bucket_capacity=bucket_capacity)
+        lookup_split=lookup_split, lookup_comms=lookup_comms,
+        bucket_capacity=bucket_capacity)
     if store is not None:
         engine.register_tiered_model(
             "dlrm", DLRM, cfg, params, state, buffers, store,
             shapes={"tiered_p99": p99_rows, "tiered_bulk": bulk_rows},
-            shard_lookup=shard_lookup,
             lookup_comms=lookup_comms, bucket_capacity=bucket_capacity)
     return engine
 

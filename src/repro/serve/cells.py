@@ -5,8 +5,9 @@ dry-run builds production-scale ShapeDtypeStruct stand-ins, these bind *real*
 trained arrays (a packed table, tower MLPs, KV caches) and parameterize the
 batch shape, so the same builder serves a 4-field test table on one CPU
 device and the Criteo-scale table on the production mesh. The dry-run serve
-cells reuse ``packed_score_step`` so the lowered computation is identical in
-both harnesses.
+cells reuse ``score_step`` (the model's own forward, its packed lookup
+partitioned by GSPMD); on one device ``packed_score_step`` compiles to the
+same program.
 
 A ``ServeCellDef`` separates *bound* inputs (params/state/buffers — device_put
 once at registration) from *request* inputs (ids/tokens/caches — fresh every
@@ -22,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.cache.tiers import tiered_hot_lookup_fn
 from repro.core.inference import packed_lookup_fn
 from repro.dist.sharding import (lm_kv_cache_pspecs, lm_logits_pspecs,
                                  lm_param_pspecs, packed_serve_pspecs,
@@ -94,35 +94,40 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
 
 
+def _topped(logits, top_k):
+    return tuple(jax.lax.top_k(logits, top_k)) if top_k is not None \
+        else logits
+
+
+def score_step(model, cfg, *, top_k: int | None = None):
+    """Eval-mode ``model.apply`` scoring, optionally topped with a candidate
+    ``top_k``: the dense baselines' step, and the dry-run's packed step
+    (its packed lookup partitioned by GSPMD over the production mesh)."""
+    def serve_step(params, state, buffers, ids):
+        logits, _, _ = model.apply(params, buffers, state, {"ids": ids},
+                                   cfg, train=False)
+        return _topped(logits, top_k)
+    return serve_step
+
+
 def packed_score_step(model, cfg, *, top_k: int | None = None,
-                      shard_lookup: bool = False, rows_axes=("model",),
-                      lookup_comms: str = "psum",
+                      rows_axes=("model",), lookup_comms: str = "psum",
                       bucket_capacity: int | None = None):
-    """The packed-table scoring computation shared by the live engine and the
-    dry-run serve cells: eval-mode forward over a packed embedding config,
+    """The packed-table scoring computation of the live engine: the packed
+    lookup, then the model's interaction net (``model.interact``),
     optionally topped with a candidate ``top_k``.
 
-    ``shard_lookup`` routes the embedding gather through
-    ``repro.dist.shard.sharded_packed_lookup`` — the fused lookup runs
-    *inside* the partitioner as a ``shard_map`` over the mesh active at
-    trace time (the ``CellCache`` compiles under the engine's mesh), with
-    subtables row-sharded over ``rows_axes``. ``lookup_comms`` picks the
-    merge collective — ``"psum"`` (dequantized partials) or ``"a2a"`` (the
+    The lookup is ``repro.dist.shard.sharded_packed_lookup``: on the mesh
+    active at trace time (the ``CellCache`` compiles under the engine's
+    mesh) it runs *inside* the partitioner as a ``shard_map``, subtables
+    row-sharded over ``rows_axes``; with no multi-device mesh it is the
+    one-device ``packed_lookup``. ``lookup_comms`` picks the merge
+    collective — ``"psum"`` (dequantized partials) or ``"a2a"`` (the
     capacity-bucketed all-to-all of the packed words, ``bucket_capacity``
-    ids per bucket) — both bit-exact, so the embeddings match the unsharded
-    cell's either way. The post-lookup interaction net (``model.interact``)
-    is the monolithic path's; on a TPU the two programs may still order its
-    f32 sums differently, so scores can differ in the last bits. Degrades to the plain forward when
-    compiled without a multi-device mesh."""
-    if not shard_lookup:
-        def serve_step(params, state, buffers, ids):
-            logits, _, _ = model.apply(params, buffers, state, {"ids": ids},
-                                       cfg, train=False)
-            if top_k is not None:
-                return tuple(jax.lax.top_k(logits, top_k))
-            return logits
-        return serve_step
-
+    ids per bucket) — both bit-exact, so the embeddings match the one-device
+    lookup's either way. On a TPU the sharded and one-device programs may
+    still order the interaction net's f32 sums differently, so scores can
+    differ in the last bits."""
     from repro.dist.shard import sharded_packed_lookup
     meta = {k: cfg.comp_cfg[k] for k in ("bits", "d", "n")}
 
@@ -133,30 +138,25 @@ def packed_score_step(model, cfg, *, top_k: int | None = None,
                                     lookup_comms=lookup_comms,
                                     bucket_capacity=bucket_capacity)
         logits, _ = model.interact(params, state, emb, gids, cfg, train=False)
-        if top_k is not None:
-            return tuple(jax.lax.top_k(logits, top_k))
-        return logits
+        return _topped(logits, top_k)
     return serve_step
 
 
 def packed_score_cell(model, cfg, params, state, buffers, *, batch: int,
                       arch: str, shape: str, dp=("data",),
-                      rows_axes=("model",), shard_lookup: bool = False,
-                      lookup_comms: str = "psum",
+                      rows_axes=("model",), lookup_comms: str = "psum",
                       bucket_capacity: int | None = None) -> ServeCellDef:
     """Batched CTR scoring from a packed table: ``ids (B, F) -> logits (B,)``.
 
     ``cfg`` must carry ``compressor="packed"`` with the table's comp_cfg;
-    ``params["embedding"]`` is the packed table pytree. ``shard_lookup``
-    compiles the ``shard_map`` lookup path and ``lookup_comms``/
-    ``bucket_capacity`` pick its merge collective (see
+    ``params["embedding"]`` is the packed table pytree. ``lookup_comms``/
+    ``bucket_capacity`` pick the sharded lookup's merge collective (see
     ``packed_score_step``); both enter the cell fingerprint, so a psum cell
     and an a2a cell never share an executable."""
     n_fields = len(cfg.fields)
     return ServeCellDef(
         arch=arch, shape=shape, kind="score", batch=batch,
-        step_fn=packed_score_step(model, cfg, shard_lookup=shard_lookup,
-                                  rows_axes=rows_axes,
+        step_fn=packed_score_step(model, cfg, rows_axes=rows_axes,
                                   lookup_comms=lookup_comms,
                                   bucket_capacity=bucket_capacity),
         bound=(params, state, buffers),
@@ -166,7 +166,7 @@ def packed_score_cell(model, cfg, params, state, buffers, *, batch: int,
         request_pspecs=(P(dp, None),),
         out_pspecs=P(dp),
         meta={"kind": "score", "batch": batch, "n_fields": n_fields,
-              "shard_lookup": shard_lookup, "lookup_comms": lookup_comms,
+              "lookup_comms": lookup_comms,
               "bucket_capacity": bucket_capacity},
         static=cfg,
     )
@@ -187,15 +187,14 @@ def baseline_score_cell(model, cfg, params, state, buffers, *, batch: int,
     n_fields = len(cfg.fields)
     return ServeCellDef(
         arch=arch, shape=shape, kind="score", batch=batch,
-        step_fn=packed_score_step(model, cfg),
+        step_fn=score_step(model, cfg),
         bound=(params, state, buffers),
         bound_pspecs=(replicate_like(params), replicate_like(state),
                       replicate_like(buffers)),
         request_specs=(_sds((batch, n_fields), jnp.int32),),
         request_pspecs=(P(dp, None),),
         out_pspecs=P(dp),
-        meta={"kind": "score", "batch": batch, "n_fields": n_fields,
-              "shard_lookup": False},
+        meta={"kind": "score", "batch": batch, "n_fields": n_fields},
         static=cfg,
     )
 
@@ -229,7 +228,6 @@ def packed_lookup_cell(table, meta, offsets, *, batch: int, n_fields: int,
 def tiered_score_cell(model, cfg, params, state, buffers, hot, meta, *,
                       batch: int, arch: str, shape: str, dp=("data",),
                       rows_axes=("model",), row_keys=("wide", "fm_linear"),
-                      shard_lookup: bool = False,
                       lookup_comms: str = "psum",
                       bucket_capacity: int | None = None) -> ServeCellDef:
     """Batched CTR scoring from a **tiered** table: ``(ids (B, F), cold_fill
@@ -245,30 +243,23 @@ def tiered_score_cell(model, cfg, params, state, buffers, hot, meta, *,
 
     ``params`` is the serving param tree *without* the ``"embedding"`` entry
     (the tiered store owns the table); ``hot`` is ``TieredTableStore.hot``.
-    ``shard_lookup`` routes the hot-tier gather through
-    ``repro.dist.shard.sharded_tiered_hot_lookup`` (``shard_map`` over the
-    mesh active at compile time, hot subtables row-sharded per
-    ``tiered_hot_pspecs``), with ``lookup_comms``/``bucket_capacity``
-    selecting the psum or capacity-bucketed a2a merge — scores still match
-    the monolithic cell either way.
+    The hot-tier gather is ``repro.dist.shard.sharded_tiered_hot_lookup``:
+    a ``shard_map`` over the mesh active at compile time (hot subtables
+    row-sharded per ``tiered_hot_pspecs``, ``lookup_comms``/
+    ``bucket_capacity`` selecting the psum or capacity-bucketed a2a merge),
+    the one-device hot lookup without one — scores match the monolithic
+    cell either way.
     """
+    from repro.dist.shard import sharded_tiered_hot_lookup
     n_fields = len(cfg.fields)
     d = int(meta["d"])
     bits = tuple(meta["bits"])
-    if shard_lookup:
-        from repro.dist.shard import sharded_tiered_hot_lookup
-
-        def hot_lookup(hot_tree, gids):
-            return sharded_tiered_hot_lookup(hot_tree, bits, d, gids,
-                                             rows_axes=rows_axes,
-                                             lookup_comms=lookup_comms,
-                                             bucket_capacity=bucket_capacity)
-    else:
-        hot_lookup = tiered_hot_lookup_fn(bits, d)
 
     def tiered_step(p, st, bufs, hot_tree, ids, cold_fill):
         gids = ids + bufs["offsets"][None, :]
-        hot_emb = hot_lookup(hot_tree, gids)                    # 0 at cold
+        hot_emb = sharded_tiered_hot_lookup(                    # 0 at cold
+            hot_tree, bits, d, gids, rows_axes=rows_axes,
+            lookup_comms=lookup_comms, bucket_capacity=bucket_capacity)
         is_hot = jnp.take(hot_tree["is_hot"], gids, axis=0)
         emb = jnp.where(is_hot[..., None], hot_emb, cold_fill)
         logits, _ = model.interact(p, st, emb, gids, cfg, train=False)
@@ -291,7 +282,7 @@ def tiered_score_cell(model, cfg, params, state, buffers, hot, meta, *,
         request_pspecs=(P(dp, None), P(dp, None, None)),
         out_pspecs=P(dp),
         meta={"kind": "tiered_score", "batch": batch, "n_fields": n_fields,
-              "shard_lookup": shard_lookup, "lookup_comms": lookup_comms,
+              "lookup_comms": lookup_comms,
               "bucket_capacity": bucket_capacity},
         static=(cfg, bits, d),
     )

@@ -158,24 +158,21 @@ class Engine:
                               *, shapes: dict[str, int],
                               lookup_split: bool = True, dp=("data",),
                               rows_axes=("model",),
-                              shard_lookup: bool = False,
                               lookup_comms: str = "psum",
                               bucket_capacity: int | None = None):
         """Register one score cell per (shape name → row capacity) for a flat
         CTR model serving from a packed table, each with its lookup-split
-        companion when ``lookup_split``. ``shard_lookup`` compiles the
-        ``shard_map`` lookup path against the engine's mesh (the fused
-        gather runs inside the partitioner — a no-op on a 1-device mesh);
-        ``lookup_comms``/``bucket_capacity`` select its merge collective
-        (psum, or the capacity-bucketed all-to-all) and enter the cell
-        fingerprint."""
+        companion when ``lookup_split``. On a multi-device mesh the lookup
+        compiles as a ``shard_map`` against the engine's mesh (the fused
+        gather runs inside the partitioner); ``lookup_comms``/
+        ``bucket_capacity`` select its merge collective (psum, or the
+        capacity-bucketed all-to-all) and enter the cell fingerprint."""
         meta = {k: cfg.comp_cfg[k] for k in ("bits", "d", "n")}
         n_fields = len(cfg.fields)
         for shape, rows in shapes.items():
             cd = packed_score_cell(model, cfg, params, state, buffers,
                                    batch=rows, arch=arch, shape=shape,
                                    dp=dp, rows_axes=rows_axes,
-                                   shard_lookup=shard_lookup,
                                    lookup_comms=lookup_comms,
                                    bucket_capacity=bucket_capacity)
             lc = None
@@ -190,7 +187,6 @@ class Engine:
     def register_tiered_model(self, arch, model, cfg, params, state, buffers,
                               store, *, shapes: dict[str, int], dp=("data",),
                               rows_axes=("model",),
-                              shard_lookup: bool = False,
                               lookup_comms: str = "psum",
                               bucket_capacity: int | None = None):
         """Register one **tiered** score cell per (shape name → row capacity)
@@ -206,7 +202,6 @@ class Engine:
             cd = tiered_score_cell(model, cfg, p, state, buffers, store.hot,
                                    store.meta, batch=rows, arch=arch,
                                    shape=shape, dp=dp, rows_axes=rows_axes,
-                                   shard_lookup=shard_lookup,
                                    lookup_comms=lookup_comms,
                                    bucket_capacity=bucket_capacity)
             reg = self._compile(cd)

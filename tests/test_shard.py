@@ -1,8 +1,9 @@
 """shard_map parity suite (ISSUE 4): all four Pallas kernels, the packed and
 tiered serve cells, and the shard_map train step on real multi-device meshes.
 
-Everything here is marked ``multidevice`` and runs in-process in the
-blocking CI job of the same name (``XLA_FLAGS`` virtualizes 4 CPU devices —
+Everything here but the one-device ownership test of the lookup routine is
+marked ``multidevice`` and runs in-process in the blocking CI job of the
+same name (``XLA_FLAGS`` virtualizes 4 CPU devices —
 see tests/conftest.py). On a single-device session the marked tests skip and
 ``test_shard_suite_subprocess_fallback`` re-runs the whole suite in a
 4-virtual-device child pytest, so tier-1 keeps the coverage.
@@ -53,6 +54,61 @@ def _random_packed_table(n=160, d=12, seed=0, row_pad_multiple=None):
 
 
 # ---------------------------------------------------------------------------
+# row-shard ownership of the one lookup routine, on one device
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("hot_fraction", [None, 0.0, 0.3, 1.0])
+@pytest.mark.parametrize("k", [2, 4])
+def test_lookup_rows_row_shards_sum_to_lookup(k, hot_fraction, rng):
+    """``core.inference._lookup_rows`` on each of ``k`` hand-cut row shards
+    of every width's subtable, told which shard it holds: the k partial
+    lookups sum to ``packed_lookup`` (no keep mask) or, with the hot bit as
+    ``keep``, to ``tiered_hot_lookup`` — zeros at cold ids. Runs without a
+    mesh, so the ownership mask the psum bodies rely on is checked in every
+    session."""
+    from repro.cache import TieredTableStore
+    from repro.cache.tiers import tiered_hot_lookup
+    from repro.core.inference import _lookup_rows
+    from repro.embeddings.frequency import zipf_frequencies
+    table, meta = _random_packed_table(n=150, row_pad_multiple=1)
+    bits, d = meta["bits"], meta["d"]
+    ids = jnp.asarray(rng.integers(0, meta["n"], size=(53,)), jnp.int32)
+    if hot_fraction is None:
+        tree, local, keep_vec = table, "local_idx", None
+        ref_fn = jax.jit(lambda t, i: packed_lookup(t, meta, i))
+    else:
+        tree = TieredTableStore(table, meta,
+                                zipf_frequencies(meta["n"], seed=1),
+                                hot_fraction).hot
+        local, keep_vec = "tier_local", tree["is_hot"]
+        ref_fn = jax.jit(lambda t, i: tiered_hot_lookup(t, bits, d, i))
+    ref = np.asarray(ref_fn(tree, ids))
+
+    def cut(sub, s):
+        rows = -(-sub.shape[0] // k)
+        padded = jnp.pad(sub, ((0, rows * k - sub.shape[0]), (0, 0)))
+        return padded[s * rows:(s + 1) * rows]
+
+    @jax.jit
+    def shard_sum(t, i):
+        keep = None if keep_vec is None else jnp.take(t["is_hot"], i, axis=0)
+        out = jnp.zeros((i.shape[0], d), jnp.float32)
+        for s in range(k):
+            out = out + _lookup_rows(
+                {key: cut(v, s) for key, v in t["subtables"].items()},
+                t["alpha"], t["beta"], bits, d,
+                jnp.take(t["width_idx"], i, axis=0),
+                jnp.take(t[local], i, axis=0), keep=keep, shard=s)
+        return out
+
+    got = np.asarray(shard_sum(tree, ids))
+    np.testing.assert_array_equal(got, ref)
+    if keep_vec is not None:
+        cold = ~np.asarray(keep_vec)[np.asarray(ids)]
+        assert not got[cold].any()
+
+
+# ---------------------------------------------------------------------------
 # kernel parity
 # ---------------------------------------------------------------------------
 
@@ -90,14 +146,13 @@ def test_packed_lookup_pad_to_shard_edge(mesh_shape, rng):
 @pytest.mark.parametrize("mesh_shape", [(1, 4), (2, 2)])
 @multidevice
 def test_embedding_bag_parity(mesh_shape, rng):
-    from repro.kernels.embedding_bag.ops import embedding_bag_kernel_sharded
     from repro.kernels.embedding_bag.ref import embedding_bag_ref
     tab = jnp.asarray(rng.normal(0, 1, (101, 16)), jnp.float32)  # odd rows
     ids = jnp.asarray(rng.integers(0, 101, (8, 5)))
     mask = jnp.asarray(rng.random((8, 5)) < 0.8)
     ref = np.asarray(jax.jit(embedding_bag_ref)(tab, ids, mask))
     with use_mesh(_mesh(mesh_shape)):
-        got = jax.jit(lambda t, i, m: embedding_bag_kernel_sharded(
+        got = jax.jit(lambda t, i, m: shard.sharded_embedding_bag(
             t, i, m))(tab, ids, mask)
     # documented tolerance: the psum reassociates each bag's sum
     np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-5, atol=1e-6)
@@ -106,15 +161,14 @@ def test_embedding_bag_parity(mesh_shape, rng):
 @pytest.mark.parametrize("mesh_shape", MESH_SHAPES)
 @multidevice
 def test_flash_attention_parity(mesh_shape, rng):
-    from repro.kernels.flash_attention.ops import (
-        flash_attention_kernel, flash_attention_kernel_sharded)
+    from repro.kernels.flash_attention.ops import flash_attention_kernel
     q = jnp.asarray(rng.normal(0, 1, (4, 32, 4, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(0, 1, (4, 32, 2, 16)), jnp.float32)  # GQA
     v = jnp.asarray(rng.normal(0, 1, (4, 32, 2, 16)), jnp.float32)
     ref = np.asarray(jax.jit(lambda a, b, c: flash_attention_kernel(
         a, b, c, causal=True))(q, k, v))
     with use_mesh(_mesh(mesh_shape)):
-        got = jax.jit(lambda a, b, c: flash_attention_kernel_sharded(
+        got = jax.jit(lambda a, b, c: shard.sharded_flash_attention(
             a, b, c, causal=True))(q, k, v)
     np.testing.assert_array_equal(np.asarray(got), ref)
 
@@ -122,8 +176,7 @@ def test_flash_attention_parity(mesh_shape, rng):
 @pytest.mark.parametrize("mesh_shape", MESH_SHAPES)
 @multidevice
 def test_mixed_expectation_parity(mesh_shape, rng):
-    from repro.kernels.mpe_qat.ops import (mixed_expectation_kernel,
-                                           mixed_expectation_kernel_sharded)
+    from repro.kernels.mpe_qat.ops import mixed_expectation_kernel
     m = len(BITS)
     rows = jnp.asarray(rng.normal(0, 3e-3, (101, 16)), jnp.float32)  # odd rows
     probs = jax.nn.softmax(jnp.asarray(rng.normal(0, 1, (101, m)),
@@ -133,7 +186,7 @@ def test_mixed_expectation_parity(mesh_shape, rng):
     ref = np.asarray(jax.jit(lambda r, p, a, b: mixed_expectation_kernel(
         r, p, a, b, BITS))(rows, probs, alpha, beta))
     with use_mesh(_mesh(mesh_shape)):
-        got = jax.jit(lambda r, p, a, b: mixed_expectation_kernel_sharded(
+        got = jax.jit(lambda r, p, a, b: shard.sharded_mixed_expectation(
             r, p, a, b, BITS))(rows, probs, alpha, beta)
     np.testing.assert_array_equal(np.asarray(got), ref)
 
@@ -145,8 +198,7 @@ def test_mixed_expectation_grad_parity(mesh_shape, rng):
     ∂β are partial sums, and the shard_map transpose sums them over the
     shards, so they equal the one-device path's up to that reassociation;
     ∂rows and ∂probs are row-local and bit-identical."""
-    from repro.kernels.mpe_qat.ops import (mixed_expectation_kernel,
-                                           mixed_expectation_kernel_sharded)
+    from repro.kernels.mpe_qat.ops import mixed_expectation_kernel
     m, d = len(BITS), 128
     rows = jnp.asarray(rng.normal(0, 3e-3, (301, d)), jnp.float32)
     probs = jax.nn.softmax(jnp.asarray(rng.normal(0, 1, (301, m)),
@@ -162,7 +214,7 @@ def test_mixed_expectation_grad_parity(mesh_shape, rng):
 
     ref = grads(mixed_expectation_kernel)
     with use_mesh(_mesh(mesh_shape)):
-        got = grads(mixed_expectation_kernel_sharded)
+        got = grads(shard.sharded_mixed_expectation)
     for a, r in zip(got[:2], ref[:2]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
     for a, r in zip(got[2:], ref[2:]):
@@ -225,8 +277,7 @@ def test_serve_cells_sharded_parity_and_zero_recompile(mesh_shape,
     ids = SyntheticCTR(spec._replace(batch_size=300)).batch(50_000)["ids"]
 
     ref_engine = build_engine(cfg, params, state, buffers, p99_rows=64,
-                              bulk_rows=256, mesh=_single_device_mesh(),
-                              shard_lookup=False)
+                              bulk_rows=256, mesh=_single_device_mesh())
     ref = ref_engine.score(ids)
 
     engine = build_engine(cfg, params, state, buffers, p99_rows=64,
@@ -251,15 +302,14 @@ def test_tiered_serve_cells_sharded_parity(mesh_shape, served_model):
     freqs = SyntheticCTR(spec).expected_frequencies()
     ids = SyntheticCTR(spec._replace(batch_size=300)).batch(60_000)["ids"]
 
-    def tiered_engine(mesh, shard_lookup):
+    def tiered_engine(mesh):
         store = TieredTableStore(res["packed_table"], res["packed_meta"],
                                  freqs, 0.3)
         return build_engine(cfg, params, state, buffers, p99_rows=64,
-                            bulk_rows=256, store=store, mesh=mesh,
-                            shard_lookup=shard_lookup)
+                            bulk_rows=256, store=store, mesh=mesh)
 
-    ref = tiered_engine(_single_device_mesh(), False).score_tiered(ids)
-    engine = tiered_engine(_mesh(mesh_shape), True)
+    ref = tiered_engine(_single_device_mesh()).score_tiered(ids)
+    engine = tiered_engine(_mesh(mesh_shape))
     got = engine.score_tiered(ids)
     np.testing.assert_array_equal(got, ref)  # hot psum + cold fill: exact
     n_compiles = engine.compile_count

@@ -227,8 +227,7 @@ def test_engine_a2a_parity_and_zero_recompile(served_model):
     ids = SyntheticCTR(spec._replace(batch_size=300)).batch(50_000)["ids"]
 
     ref_engine = build_engine(cfg, params, state, buffers, p99_rows=64,
-                              bulk_rows=256, mesh=host_mesh(1, 1),
-                              shard_lookup=False)
+                              bulk_rows=256, mesh=host_mesh(1, 1))
     ref = ref_engine.score(ids)
 
     engine = build_engine(cfg, params, state, buffers, p99_rows=64,
@@ -255,7 +254,7 @@ def test_lookup_comms_forks_cell_fingerprint(served_model):
     cfg, params, state, buffers, spec, res = served_model
     mk = lambda comms, cap: packed_score_cell(  # noqa: E731
         DLRM, cfg, params, state, buffers, batch=64, arch="dlrm",
-        shape="p99", shard_lookup=True, lookup_comms=comms,
+        shape="p99", lookup_comms=comms,
         bucket_capacity=cap)
     fps = {mk("psum", None).fingerprint, mk("a2a", None).fingerprint,
            mk("a2a", 8).fingerprint}
